@@ -53,6 +53,8 @@ def main():
                             figa3_quant, kernels_bench, scan_bench,
                             table_a1_comms, table_a2_bert, table_a3_memory)
     from benchmarks.common import clear_runner_cache
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     suites = {
         "scan": scan_bench.main,
         "fig2": fig2_heterogeneity.main,

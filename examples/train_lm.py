@@ -26,5 +26,5 @@ else:
     size = dict(d_model=256, layers=4, vocab=512, seq=256)
 
 final_loss = train(arch="yi-9b", reduced=True, batch=8, steps=args.steps,
-                   algo=args.algo, **size)
+                   algo=args.algo, **size).loss
 sys.exit(0 if final_loss < 5.5 else 1)

@@ -197,6 +197,28 @@ class ModelConfig:
         active_expert = self.num_layers * self.num_experts_per_tok * 3 * self.d_model * self.moe_d_ff
         return int(full - all_expert + active_expert)
 
+    def cut_depth(self, layers: int) -> "ModelConfig":
+        """The published model with at most `layers` layers and every width
+        unchanged. Each stage keeps ``r`` whole periods of its pattern (never
+        more than its published repeats), with ``r`` the largest count that
+        fits, so every layer kind stays in its published ratio. One period
+        of every stage is the floor; fewer layers than that is an error."""
+        top = max(reps for _, reps in self.stages)
+        for r in range(top, 0, -1):
+            stages = tuple((p, min(reps, r)) for p, reps in self.stages)
+            nl = sum(len(p) * k for p, k in stages)
+            if nl <= layers:
+                break
+        else:
+            raise ValueError(
+                f"{self.name}: one period of every stage needs {nl} layers, "
+                f"asked for {layers}")
+        kw: Dict[str, Any] = dict(name=f"{self.name}-{nl}l", num_layers=nl,
+                                  stages=stages)
+        if self.is_encoder_decoder:
+            kw.update(num_encoder_layers=min(self.num_encoder_layers, layers))
+        return dataclasses.replace(self, **kw)
+
     def reduced(self, *, layers: int = 2, d_model: int = 256, experts: int = 4,
                 vocab: int = 512) -> "ModelConfig":
         """Reduced same-family variant for CPU smoke tests."""

@@ -50,6 +50,7 @@ import functools
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core.scan_staleness import make_staleness_runner
 from repro.sharding.rules import use_rules
@@ -71,7 +72,10 @@ def staleness_mesh(*, model: Optional[int] = None):
         model = 2 if n % 2 == 0 else 1
     if n % model != 0:
         raise ValueError(f"model={model} does not divide device count {n}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    # Auto axes: `shard()` places logical constraints, which Explicit axes
+    # (the jax.make_mesh default) refuse
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_sharded_staleness_runner(*, mesh, **kwargs):

@@ -384,10 +384,14 @@ def _staleness_program(*, grad_fn: Callable, params0,
                        resync_every: Optional[int] = None,
                        checkify_invariants: bool = False,
                        k_batch: int = 1):
-    """The protocol as two pure functions: ``(init_fn, chunk_fn, marks)``.
+    """The protocol as two pure functions: ``(init_fn, chunk_fn, marks, w0)``.
 
-    ``init_fn(key, lr) -> carry`` builds the initial scan carry (init-batch
-    cache seed, ring slot 0, eval snapshot buffer); ``chunk_fn(carry,
+    ``init_fn(key, lr, w0) -> carry`` builds the initial scan carry
+    (init-batch cache seed, ring slot 0, eval snapshot buffer) from the f32
+    starting model `w0` (the raveled vector or the params pytree, per
+    layout). It takes `w0` as an argument rather than closing over it, so a
+    jitted init does not embed a published-width model in its executable as
+    a constant. ``chunk_fn(carry,
     gumbels, tau_raw, leave_at, rejoin_at, lr) -> (carry, outs)`` scans any
     slice of the event stream and composes: running it over consecutive
     slices is bit-identical to one scan over their concatenation, because
@@ -467,8 +471,8 @@ def _staleness_program(*, grad_fn: Callable, params0,
         # redundantly per device; only server state shards (see
         # sharding/rules.replicate for the CPU-SPMD rationale)
         pin_payload = replicate
-        init_ring = lambda: shard(
-            jnp.zeros((S, d_tpl), jnp.float32).at[0].set(w0),
+        init_ring = lambda w: shard(
+            jnp.zeros((S, d_tpl), jnp.float32).at[0].set(w),
             (None, "cache_d"))
         rd_ring, ap_ring = ring_read, ring_append
 
@@ -499,8 +503,8 @@ def _staleness_program(*, grad_fn: Callable, params0,
         # computation and keep its (data, model) layout; the tree-cache row
         # writes inherit it per leaf
         pin_payload = lambda p: p
-        init_ring = lambda: tree_cache_set_row(
-            init_tree_cache(S, w0, history_dtype), 0, w0)
+        init_ring = lambda w: tree_cache_set_row(
+            init_tree_cache(S, w, history_dtype), 0, w)
 
         def rd_ring(ring, cursor, tau):
             return tree_cache_row(ring, jnp.mod(cursor - tau, S))
@@ -539,7 +543,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
     else:
         raise ValueError(f"unknown layout {layout!r}")
 
-    def init_fn(key, lr):
+    def init_fn(key, lr, w0):
         lr = jnp.asarray(lr, jnp.float32)
         w = w0
         if wants_init:
@@ -555,7 +559,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
             state = agg.init_state(n, d_tpl, None)
             t0 = 0
 
-        ring = init_ring()
+        ring = init_ring(w0)
         cursor = jnp.asarray(0, jnp.int32)
         if wants_init:           # history = [w^0, w^1] after the init update
             ring, cursor = ap_ring(ring, cursor, w, True)
@@ -848,7 +852,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
             return _chunk_impl(carry, gumbels, tau_raw, leave_at, rejoin_at,
                                lr, None, None, None)
 
-    return init_fn, chunk_fn, marks
+    return init_fn, chunk_fn, marks, w0
 
 
 def make_staleness_runner(*, grad_fn: Callable, params0,
@@ -903,7 +907,7 @@ def make_staleness_runner(*, grad_fn: Callable, params0,
     (`build_staleness_randomness(..., k_batch=...)`). ``k_batch=1``
     compiles the original per-event program bit-identically."""
     do_checkify = sanitize.enabled(checkify_invariants)
-    init_fn, chunk_fn, marks = _staleness_program(
+    init_fn, chunk_fn, marks, w0 = _staleness_program(
         grad_fn=grad_fn, params0=params0, aggregator=aggregator,
         n_clients=n_clients, T=T, beta=beta, server_lr=server_lr,
         tau_max=tau_max, speed_skew=speed_skew, eval_marks=eval_marks,
@@ -914,7 +918,7 @@ def make_staleness_runner(*, grad_fn: Callable, params0,
         checkify_invariants=do_checkify, k_batch=k_batch)
 
     def _run(key, gumbels, tau_raw, leave_at, rejoin_at, lr, *guard_args):
-        carry = init_fn(key, lr)
+        carry = init_fn(key, lr, w0)
         carry, outs = chunk_fn(carry, gumbels, tau_raw, leave_at, rejoin_at,
                                lr, *guard_args)
         extras = {}
@@ -940,7 +944,8 @@ class ChunkedStalenessRunner:
     generic pytree saver (repro/checkpoint) and a resumed run continues
     exactly. ``marks`` mirrors the baked `eval_marks` static (None without
     an eval cadence); with marks the carry holds ``snaps``/``hits`` for
-    `_apply_evals`."""
+    `_apply_evals`. `chunk` consumes (donates) the carry it is given, except
+    in the checkify build."""
     init: Callable
     chunk: Callable
     marks: Optional[jnp.ndarray]
@@ -972,7 +977,7 @@ def make_chunked_staleness_runner(*, mesh=None, **kwargs
     into `chunk` — see `make_staleness_runner`."""
     do_checkify = sanitize.enabled(kwargs.pop("checkify_invariants", None))
     kwargs["checkify_invariants"] = do_checkify
-    init_fn, chunk_fn, marks = _staleness_program(**kwargs)
+    init_fn, chunk_fn, marks, w0 = _staleness_program(**kwargs)
     tau_max = kwargs.get("tau_max")
     if tau_max is None:
         tau_max = default_tau_max(kwargs["beta"])
@@ -981,20 +986,22 @@ def make_chunked_staleness_runner(*, mesh=None, **kwargs
     k_batch = kwargs.get("k_batch", 1)
     jit_init = jax.jit(init_fn)
     # only `chunk` carries checks (init traces none), so only it needs the
-    # checkify functionalization + throw wrapper
+    # checkify functionalization + throw wrapper. The production chunk
+    # donates its carry: the old and new carries (ring, cache, model) are
+    # never live together, which is what lets a published-width model fit
+    # one chip. A caller must not read a carry after passing it to `chunk`.
     jit_chunk = (sanitize.wrap_checked(chunk_fn) if do_checkify
-                 else jax.jit(chunk_fn))
+                 else jax.jit(chunk_fn, donate_argnums=0))
     if mesh is None:
-        return ChunkedStalenessRunner(jit_init, jit_chunk, marks, tau_max,
-                                      kwargs.get("layout", "flat"),
-                                      guards=guards,
-                                      resync_every=resync_every,
-                                      checkify_invariants=do_checkify,
-                                      k_batch=k_batch)
+        return ChunkedStalenessRunner(
+            lambda key, lr: jit_init(key, lr, w0), jit_chunk, marks, tau_max,
+            kwargs.get("layout", "flat"), guards=guards,
+            resync_every=resync_every, checkify_invariants=do_checkify,
+            k_batch=k_batch)
 
     def init(key, lr):
         with use_rules(mesh):
-            return jit_init(key, lr)
+            return jit_init(key, lr, w0)
 
     def chunk(carry, *args):
         with use_rules(mesh):

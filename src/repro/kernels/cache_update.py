@@ -17,12 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-try:  # TPU-specific memory spaces (fall back gracefully off-TPU)
-    from jax.experimental.pallas import tpu as pltpu
-    SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import default_interpret
 
@@ -62,8 +57,7 @@ def cache_row_update(u, g, c_row, old_scale, new_scale, inv_n, *,
                          jnp.asarray(inv_n, jnp.float32)])
     grid = (dp // block_d,)
     spec = pl.BlockSpec((block_d,), lambda i: (i,))
-    sspec = (pl.BlockSpec(memory_space=SMEM) if SMEM is not None
-             else pl.BlockSpec((3,), lambda i: (0,)))
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     u_new, c_new = pl.pallas_call(
         _kernel,
         grid=grid,

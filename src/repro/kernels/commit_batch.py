@@ -57,20 +57,24 @@ def _kernel(lanes_ref, mats_ref, g_ref, c_ref, v_ref,
         stored = Gs.astype(c.dtype)
         rows_ref[...] = jnp.where(vcol, stored, c)
         dq_new = stored.astype(jnp.float32)
-    s_old = jnp.dot(vf, old, preferred_element_type=jnp.float32)
-    sd = jnp.dot(vf, dq_new, preferred_element_type=jnp.float32) - s_old
+    s_old = _matvec(lanes[2:3], old)
+    sd = _matvec(lanes[2:3], dq_new) - s_old
     z = jnp.zeros_like(sd)
-    sa = (jnp.dot(lanes[3], old, preferred_element_type=jnp.float32)
-          if has_a else z)
-    sb = (jnp.dot(lanes[4], old, preferred_element_type=jnp.float32)
-          if has_b else z)
-    sg = (jnp.dot(lanes[5], Gs, preferred_element_type=jnp.float32)
-          if has_g else z)
-    basis = jnp.concatenate(
-        [v_ref[...], sd[None], sa[None], sb[None], sg[None]], axis=0)
-    out = jnp.dot(mats_ref[...], basis, preferred_element_type=jnp.float32)
+    sa = _matvec(lanes[3:4], old) if has_a else z
+    sb = _matvec(lanes[4:5], old) if has_b else z
+    sg = _matvec(lanes[5:6], Gs) if has_g else z
+    basis = jnp.concatenate([v_ref[...], sd, sa, sb, sg], axis=0)
+    out = _matvec(mats_ref[...], basis)
     vecs_ref[...] = out[:n_vecs]
     upd_ref[...] = out[n_vecs]
+
+
+def _matvec(w, x):
+    # (r, K) @ (K, bd) on the MXU. Both operands stay 2-D: the TPU compiler
+    # refuses a rank-1 LHS. HIGHEST multiplies in full f32, as the XLA
+    # oracle's broadcast-multiply-reduce does; the default rounds to bf16.
+    return jnp.dot(w, x, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

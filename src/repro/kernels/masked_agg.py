@@ -21,10 +21,12 @@ BLOCK_D = 2048
 
 
 def _kernel(w_ref, c_ref, out_ref):
-    # w_ref (n,) f32 = mask*scale/denominator ; c_ref (n, bd) int8
-    w = w_ref[...]
+    # w_ref (n,) f32 = mask*scale/denominator ; c_ref (n, bd) int8. The LHS
+    # is lifted to (1, n): the TPU compiler refuses a rank-1 dot operand.
+    w = w_ref[...][None]
     c = c_ref[...].astype(jnp.float32)
-    out_ref[...] = jnp.dot(w, c, preferred_element_type=jnp.float32)
+    out_ref[...] = jnp.dot(w, c, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

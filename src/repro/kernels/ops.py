@@ -10,7 +10,10 @@
 
 The default (`backend=None`) routes to "pallas" on TPU — where the kernels
 actually compile — and "xla" elsewhere, so the scanned ACE/ACED steps get the
-fused kernels exactly when the hardware supports them. ``REPRO_NO_PALLAS=1``
+fused kernels exactly when the hardware supports them. Under a multi-device
+mesh (an enclosing `use_rules` context) it routes to "xla" too: the SPMD
+partitioner cannot partition a Mosaic kernel, and it can partition the
+oracle. ``REPRO_NO_PALLAS=1``
 (backend.no_pallas, read at trace time) forces "xla" everywhere — the
 runtime escape hatch selecting the oracle path uniformly across every
 kernel without editing call sites; an explicit ``backend=`` still wins.
@@ -26,6 +29,7 @@ from repro.kernels import quant as _q
 from repro.kernels import ref
 from repro.kernels import row_delta as _rd
 from repro.kernels.backend import fused_commit_enabled, no_pallas
+from repro.sharding.rules import active_mesh
 
 __all__ = [
     "cache_row_update", "commit_batch", "default_backend",
@@ -35,9 +39,10 @@ __all__ = [
 
 
 def default_backend() -> str:
-    if no_pallas():
-        return "xla"
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    mesh = active_mesh()
+    sharded = mesh is not None and mesh.size > 1
+    tpu = jax.default_backend() == "tpu"
+    return "pallas" if tpu and not (sharded or no_pallas()) else "xla"
 
 
 def _interpret(backend: str):

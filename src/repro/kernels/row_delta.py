@@ -18,12 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-try:  # TPU-specific memory spaces (fall back gracefully off-TPU)
-    from jax.experimental.pallas import tpu as pltpu
-    SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import default_interpret
 
@@ -61,8 +56,7 @@ def row_delta(g, c_row, old_scale, new_scale, *,
                          jnp.asarray(new_scale, jnp.float32)])
     grid = (dp // block_d,)
     spec = pl.BlockSpec((block_d,), lambda i: (i,))
-    sspec = (pl.BlockSpec(memory_space=SMEM) if SMEM is not None
-             else pl.BlockSpec((2,), lambda i: (0,)))
+    sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
     delta, c_new = pl.pallas_call(
         _kernel,
         grid=grid,

@@ -24,7 +24,9 @@ Example (CPU, ~0.8M-param yi-family model, 200 server iterations):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +35,7 @@ import numpy as np
 from repro.checkpoint import restore_train_checkpoint, save_train_checkpoint
 from repro.configs.registry import afl_config, get_config
 from repro.core.aggregators import make_aggregator
+from repro.core.distributed import afl_state_bytes, history_ring_bytes
 from repro.core.fl_tasks import make_lm_task
 from repro.core.scan_engine import default_n_events
 from repro.core.scan_staleness import (build_fault_schedule,
@@ -40,7 +43,30 @@ from repro.core.scan_staleness import (build_fault_schedule,
                                        make_chunked_staleness_runner)
 from repro.core.scan_sharded import staleness_mesh
 from repro.core.staleness_sim import StalenessSimulator, default_tau_max
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim import sqrt_nt_schedule
+
+#: JAX's compile-phase duration events, summed into `TrainResult.compile_s`
+_COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+
+
+@dataclasses.dataclass
+class TrainResult:
+    """What one `main`/`train` call did. `loss` is the mean of the last 20
+    emitted client losses (the eval loss when a resume left none to run).
+    `events_per_s` counts arrivals over the chunks after the first, so it
+    leaves out compilation (None when only one chunk ran). `peak_bytes` is
+    the device's ``peak_bytes_in_use`` where the backend reports it."""
+    loss: float
+    t: int
+    T: int
+    param_count: int
+    state_bytes: int
+    compile_s: float
+    events_per_s: Optional[float] = None
+    peak_bytes: Optional[int] = None
 
 
 def _parser() -> argparse.ArgumentParser:
@@ -48,7 +74,11 @@ def _parser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default="yi-9b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--d-model", type=int, default=256)
-    ap.add_argument("--layers", type=int, default=4)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth. With --reduced: the toy model's layers "
+                    "(default 4). Without: cut the published model to this "
+                    "many layers, keeping whole periods of every stage and "
+                    "every width (default: all layers)")
     ap.add_argument("--steps", type=int, default=200,
                     help="server iterations T")
     ap.add_argument("--batch", type=int, default=8)
@@ -115,7 +145,7 @@ def _parser() -> argparse.ArgumentParser:
     return ap
 
 
-def train(**overrides) -> float:
+def train(**overrides) -> TrainResult:
     """Programmatic entry point: parser defaults + keyword overrides
     (underscored option names, e.g. ``train(reduced=True, d_model=64)``) —
     examples/train_lm.py uses this instead of re-encoding argv."""
@@ -127,15 +157,37 @@ def train(**overrides) -> float:
     return _run(args)
 
 
-def main(argv=None) -> float:
+def main(argv=None) -> TrainResult:
+    use_compile_cache()
     return _run(_parser().parse_args(argv))
 
 
-def _run(args) -> float:
+def _run(args) -> TrainResult:
+    compile_s = [0.0]
+
+    def on_duration(event, secs, **_):
+        if event in _COMPILE_EVENTS:
+            compile_s[0] += secs
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        return _train(args, compile_s)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+
+
+def model_config(args):
+    """The model the options name: the toy `reduced` variant with
+    --reduced, else the published config, depth cut by --layers."""
     cfg = get_config(args.arch)
     if args.reduced:
-        cfg = cfg.reduced(layers=args.layers, d_model=args.d_model,
-                          vocab=args.vocab)
+        return cfg.reduced(layers=args.layers or 4, d_model=args.d_model,
+                           vocab=args.vocab)
+    return cfg.cut_depth(args.layers) if args.layers else cfg
+
+
+def _train(args, compile_s) -> TrainResult:
+    cfg = model_config(args)
     aflc = afl_config(args.arch, algorithm=args.algo,
                       n_clients=args.n_clients, delay_beta=args.beta,
                       cache_dtype=args.cache_dtype, k_batch=args.k_batch)
@@ -178,6 +230,22 @@ def _run(args) -> float:
               f"resync_every={args.resync_every or 'off'} "
               f"injected={kinds}")
     resync_every = args.resync_every or None
+    state_bytes = (afl_state_bytes(aflc, task.params0, "tree", guards=guards,
+                                   resync_every=resync_every)
+                   + history_ring_bytes(task.params0, tau_max,
+                                        args.history_dtype))
+
+    def result(loss, t, events_per_s=None):
+        stats = jax.local_devices()[0].memory_stats() or {}
+        res = TrainResult(loss=loss, t=t, T=T,
+                          param_count=cfg.param_count(),
+                          state_bytes=state_bytes, compile_s=compile_s[0],
+                          events_per_s=events_per_s,
+                          peak_bytes=stats.get("peak_bytes_in_use"))
+        print(f"final loss (mean last 20): {loss:.4f} t={t}/{T} "
+              f"state_bytes={state_bytes} compile_s={res.compile_s:.2f} "
+              f"ev/s={events_per_s} peak_bytes={res.peak_bytes}")
+        return res
 
     if args.driver == "host":
         sim = StalenessSimulator(
@@ -187,11 +255,10 @@ def _run(args) -> float:
             replay=rand, faults=faults, clip_norm=args.clip_norm,
             resync_every=resync_every, k_batch=args.k_batch)
         res = sim.run(T)
-        final = float(np.mean(res.losses[-20:]))
         if res.faults:
             print(f"guard counters: {res.faults}")
-        print(f"final loss (mean last 20): {final:.4f}")
-        return final
+        return result(float(np.mean(res.losses[-20:])),
+                      int(res.ts[-1]) + 1 if res.ts else 0)
 
     mesh = staleness_mesh() if args.mesh == "auto" else None
     runner = make_chunked_staleness_runner(
@@ -214,6 +281,7 @@ def _run(args) -> float:
     losses: list = []
     t0 = time.time()
     events_done, last_log = 0, 0
+    steady = None     # (time, events) once the first chunk has compiled
     for lo in range(e0, n_events, C):
         # tail guard: the final chunk is sliced exactly, so the snapshot /
         # checkpoint cursor `hi` never lands past the event schedule even
@@ -231,6 +299,8 @@ def _run(args) -> float:
         losses.extend(np.asarray(outs["loss"])[em].tolist())
         events_done += hi - lo
         t_now = int(carry["t"])
+        if steady is None:
+            steady = (time.time(), events_done)
         if len(losses) - last_log >= args.log_every or hi >= n_events:
             last_log = len(losses)
             dt = time.time() - t0
@@ -244,14 +314,18 @@ def _run(args) -> float:
         if t_now >= T:
             break
 
+    eps = None
+    if steady is not None and events_done > steady[1]:
+        eps = ((events_done - steady[1]) * args.k_batch
+               / (time.time() - steady[0]))
     ev = task.eval_fn(carry["w"])
     if guards:
         counters = {k: int(v) for k, v in carry["guards"].items()}
         print(f"guard counters: {counters}")
+    print(f"eval={ev}")
     # resumed past the event budget => no fresh losses; report eval loss
     final = float(np.mean(losses[-20:])) if losses else ev["loss"]
-    print(f"final loss (mean last 20): {final:.4f}  eval={ev}")
-    return final
+    return result(final, int(carry["t"]), eps)
 
 
 if __name__ == "__main__":

@@ -61,6 +61,12 @@ def _active():
     return getattr(_state, "ctx", None)
 
 
+def active_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing `use_rules` context, or None."""
+    ctx = _active()
+    return ctx[0] if ctx else None
+
+
 def logical_to_spec(axes: Sequence[Optional[str]],
                     rules: Optional[Dict] = None,
                     mesh: Optional[Mesh] = None) -> P:

@@ -137,6 +137,25 @@ def test_no_pallas_env_forces_xla(monkeypatch):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_default_backend_policy(monkeypatch):
+    """Pallas on a TPU; the XLA oracle off it, under ``REPRO_NO_PALLAS``,
+    and under a multi-device mesh (the SPMD partitioner cannot partition a
+    Mosaic kernel). A one-device mesh keeps the kernels."""
+    import types
+    from repro.sharding.rules import use_rules
+    monkeypatch.delenv("REPRO_NO_PALLAS", raising=False)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    assert ops.default_backend() == "xla"
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.default_backend() == "pallas"
+    with use_rules(types.SimpleNamespace(size=1)):
+        assert ops.default_backend() == "pallas"
+    with use_rules(types.SimpleNamespace(size=4)):
+        assert ops.default_backend() == "xla"
+    monkeypatch.setenv("REPRO_NO_PALLAS", "1")
+    assert ops.default_backend() == "xla"
+
+
 # --- check_commit_batch sanitizer tripwires --------------------------------
 
 def _checked(fn):

@@ -1,5 +1,9 @@
 """Launch/dry-run machinery unit tests (the 512-device runs live in
-src/repro/launch/dryrun.py; here we test its components on 1 device)."""
+src/repro/launch/dryrun.py; here we test its components on 1 device),
+the trainer's depth cut, and the compile-cache helper."""
+import dataclasses
+from pathlib import Path
+
 import pytest
 
 from repro.configs.base import INPUT_SHAPES
@@ -62,3 +66,58 @@ def test_window_reduces_analytic_attention():
     swa = get_config("gemma2-2b", shape="long_500k")
     B, L = 1, 32768
     assert forward_flops(swa, B, L) < forward_flops(full, B, L)
+
+
+def _published_widths(cfg):
+    depth = {"name", "num_layers", "stages", "num_encoder_layers"}
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)
+            if f.name not in depth}
+
+
+@pytest.mark.parametrize("arch,layers,want_reps", [
+    ("mamba2-780m", 2, (2,)),
+    ("yi-9b", 3, (3,)),
+    ("gemma2-2b", 5, (2,)),          # (local, global) period of 2
+    ("zamba2-1.2b", 7, (1, 1)),      # one period of each of the two stages
+    ("zamba2-1.2b", 20, (3, 2)),     # second stage capped at its 2 repeats
+])
+def test_layers_without_reduced_cuts_depth_only(arch, layers, want_reps):
+    """--layers without --reduced keeps every published width and whole
+    periods of every stage pattern; only the repeats shrink."""
+    from repro.launch.train import _parser, model_config
+    pub = get_config(arch)
+    cfg = model_config(_parser().parse_args(
+        ["--arch", arch, "--layers", str(layers)]))
+    assert _published_widths(cfg) == _published_widths(pub)
+    assert [p for p, _ in cfg.stages] == [p for p, _ in pub.stages]
+    assert tuple(r for _, r in cfg.stages) == want_reps
+    assert cfg.num_layers == sum(len(p) * r for p, r in cfg.stages) <= layers
+
+
+def test_layers_below_one_period_is_refused():
+    with pytest.raises(ValueError, match="one period of every stage"):
+        get_config("zamba2-1.2b").cut_depth(6)
+
+
+def test_no_layers_keeps_the_published_model():
+    from repro.launch.train import _parser, model_config
+    assert model_config(_parser().parse_args(["--arch", "zamba2-1.2b"])) \
+        == get_config("zamba2-1.2b")
+
+
+def test_compile_cache_dir_respects_env(monkeypatch):
+    """`JAX_COMPILATION_CACHE_DIR` wins and nothing is set in code;
+    without it the cache goes to the fixed in-checkout path."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.use_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = Path(__file__).resolve().parents[1]
+    want = str(root / ".jax_cache")
+    assert compile_cache.compile_cache_dir() == want
+    assert compile_cache.use_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.sharding.auto import _guard, infer_batch_shardings, param_spec
 from repro.sharding.rules import logical_to_spec, shard, use_rules
@@ -13,7 +13,8 @@ from repro.sharding.rules import logical_to_spec, shard, use_rules
 def mesh():
     if jax.device_count() < 1:
         pytest.skip("no devices")
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_shard_noop_without_mesh():

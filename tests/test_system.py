@@ -16,7 +16,8 @@ def test_train_driver_loss_decreases(tmp_path):
                         "--batch", "8", "--steps", "120", "--algo", "ace",
                         "--n-clients", "4", "--lr-scale", "1.0",
                         "--log-every", "60",
-                        "--ckpt-dir", str(tmp_path), "--ckpt-every", "60"])
+                        "--ckpt-dir", str(tmp_path), "--ckpt-every", "60"]
+                       ).loss
     # ~ln(256)+0.4 at init; must have made clear progress in 120 ACE steps
     assert final < 5.75
 
@@ -27,7 +28,7 @@ def test_train_driver_resumes_from_checkpoint(tmp_path):
             "--n-clients", "4", "--ckpt-dir", str(tmp_path),
             "--ckpt-every", "10", "--log-every", "50"]
     train_main(args + ["--steps", "10"])
-    final = train_main(args + ["--steps", "20"])   # resumes at 10
+    final = train_main(args + ["--steps", "20"]).loss   # resumes at 10
     assert np.isfinite(final)
 
 
@@ -37,7 +38,7 @@ def test_train_driver_all_algorithms(algo):
                         "--d-model", "128", "--layers", "2", "--vocab", "128",
                         "--seq", "64", "--batch", "2", "--steps", "20",
                         "--algo", algo, "--n-clients", "4",
-                        "--log-every", "20"])
+                        "--log-every", "20"]).loss
     assert np.isfinite(final)
 
 

@@ -1,0 +1,9 @@
+"""tick_ring_ms: device time per scan tick of the ring append: the new model
+written to the history ring (the `afl.ring` stage), in ms: the stage's self
+time on device 0 over the traced window, over the window's ticks. The stage of
+each op is read from the compiled chunk (`bench/tick_stages.py`)."""
+import tick_stages
+
+
+def read(record):
+    return tick_stages.tick_ms(record, "afl.ring")

@@ -85,7 +85,9 @@ accumulated drift.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -105,6 +107,42 @@ from repro.core.staleness_sim import (FAULT_BYZANTINE, FAULT_EXPLODE,
                                       NEVER, default_tau_max,
                                       staleness_client_probs)
 from repro.sharding.rules import replicate, shard, use_rules
+
+#: The stages of one scan tick, in code order. Each wraps its part of `step`
+#: and `step_k` in a `jax.named_scope`, so the compiled chunk's op metadata —
+#: and a profile of it — names the stage every op came from (see
+#: `ChunkedStalenessRunner.op_stages`). "afl.guards" is built only with
+#: guards and "afl.resync" only with `resync_every`. Outputs, the next t and
+#: the guard counters stay outside every stage.
+STAGES = ("afl.sample", "afl.stale_read", "afl.client", "afl.guards",
+          "afl.commit", "afl.select", "afl.resync", "afl.update", "afl.ring")
+
+#: one instruction of an HLO module's text, with its op_name metadata if any
+_HLO_INSTR = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?(?:op_name="((?:[^"\\]|\\.)*)"|$)')
+#: a stage scope as a component of an op_name, also under a transform
+#: wrapper such as `transpose(jvp(afl.client))`
+_STAGE_COMPONENT = re.compile(r"(?:^|/)(?:\w+\()*(afl\.\w+)")
+
+
+def _stage(name: str):
+    """The named scope of the tick stage `name` (one of `STAGES`)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown tick stage {name!r}")
+    return jax.named_scope(name)
+
+
+def hlo_op_stages(hlo_text: str) -> Dict[str, str]:
+    """Each instruction of an HLO module's text mapped to the outermost stage
+    of `STAGES` in its ``op_name`` metadata, or "" when it has none."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        names = _STAGE_COMPONENT.findall(m.group(2) or "")
+        out[m.group(1)] = next((n for n in names if n in STAGES), "")
+    return out
 
 
 @dataclasses.dataclass
@@ -589,66 +627,74 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 g_row, traw, f_kind, f_scale = ev
             else:
                 g_row, traw = ev
-            g_row = shard(g_row, ("cache_clients",))
             t = carry["t"]
-            # availability: traced-t windows folded into the sampling logits
-            gone = jnp.logical_and(leave_at <= t, t < rejoin_at)
-            logits = jnp.where(gone, -jnp.inf, log_probs)
-            # every client inside its window: no arrival is possible — the
-            # protocol freezes (no emission, model and aggregator state held)
-            # and t fast-forwards to the earliest rejoin; the host reference
-            # performs the same jump (or stops when none rejoins before T)
-            any_alive = jnp.any(~gone)
-            thaw_t = jnp.minimum(
-                jnp.min(jnp.where(gone, rejoin_at, NEVER)), T)
-            j = jnp.argmax(logits + g_row).astype(jnp.int32)
-            tau_req = jnp.floor(traw).astype(jnp.int32)
-            if guards:   # injected over-stale request; clamped for the read
-                tau_req = jnp.where(f_kind == FAULT_OVERSTALE, tau_max + 1,
-                                    tau_req)
-            tau = jnp.minimum(tau_req,
-                              jnp.minimum(tau_max, carry["n_upd"]))
-            w_stale = rd_ring(carry["ring"], carry["cursor"], tau)
-            payload, loss, key = payload_fn(w_stale, j, carry["key"])
-            payload = pin_payload(payload)
+            with _stage("afl.sample"):
+                g_row = shard(g_row, ("cache_clients",))
+                # availability: traced-t windows folded into the sampling
+                # logits
+                gone = jnp.logical_and(leave_at <= t, t < rejoin_at)
+                logits = jnp.where(gone, -jnp.inf, log_probs)
+                # every client inside its window: no arrival is possible —
+                # the protocol freezes (no emission, model and aggregator
+                # state held) and t fast-forwards to the earliest rejoin; the
+                # host reference performs the same jump (or stops when none
+                # rejoins before T)
+                any_alive = jnp.any(~gone)
+                thaw_t = jnp.minimum(
+                    jnp.min(jnp.where(gone, rejoin_at, NEVER)), T)
+                j = jnp.argmax(logits + g_row).astype(jnp.int32)
+                tau_req = jnp.floor(traw).astype(jnp.int32)
+                if guards:   # injected over-stale request; clamped for read
+                    tau_req = jnp.where(f_kind == FAULT_OVERSTALE,
+                                        tau_max + 1, tau_req)
+                tau = jnp.minimum(tau_req,
+                                  jnp.minimum(tau_max, carry["n_upd"]))
+            with _stage("afl.stale_read"):
+                w_stale = rd_ring(carry["ring"], carry["cursor"], tau)
+            with _stage("afl.client"):
+                payload, loss, key = payload_fn(w_stale, j, carry["key"])
+                payload = pin_payload(payload)
             if guards:
-                # fault injection: one scalar multiplier covers NAN (payload
-                # goes non-finite), EXPLODE (norm blow-up by f_scale) and
-                # BYZANTINE (sign flip); clean events multiply by 1.0 — an
-                # f32 identity, so a no-fault guarded run tracks the
-                # unguarded trajectory exactly
-                mult = jnp.where(f_kind == FAULT_NAN, jnp.float32(jnp.nan),
-                                 jnp.float32(1.0))
-                mult = mult * jnp.where(f_kind == FAULT_EXPLODE, f_scale,
-                                        jnp.float32(1.0))
-                mult = jnp.where(f_kind == FAULT_BYZANTINE, -mult, mult)
-                payload = jax.tree.map(lambda p: p * mult, payload)
-                finite = jnp.asarray(True)
-                for leaf in jax.tree.leaves(payload):
-                    finite = jnp.logical_and(finite,
-                                             jnp.all(jnp.isfinite(leaf)))
-                gnorm = _tree_global_norm(payload)
-                # NaN gnorm compares False: a quarantined payload is never
-                # also counted as clipped
-                do_clip = jnp.logical_and(clip_norm > 0, gnorm > clip_norm)
-                cscale = jnp.where(
-                    do_clip, clip_norm / jnp.maximum(gnorm, 1e-12),
-                    jnp.float32(1.0))
-                payload = jax.tree.map(lambda p: p * cscale, payload)
-                reject = tau_req > tau_max
-                ok = jnp.logical_and(finite, jnp.logical_not(reject))
-                proc = jnp.logical_and(any_alive, ok)
+                with _stage("afl.guards"):
+                    # fault injection: one scalar multiplier covers NAN
+                    # (payload goes non-finite), EXPLODE (norm blow-up by
+                    # f_scale) and BYZANTINE (sign flip); clean events
+                    # multiply by 1.0 — an f32 identity, so a no-fault
+                    # guarded run tracks the unguarded trajectory exactly
+                    mult = jnp.where(f_kind == FAULT_NAN, jnp.float32(jnp.nan),
+                                     jnp.float32(1.0))
+                    mult = mult * jnp.where(f_kind == FAULT_EXPLODE, f_scale,
+                                            jnp.float32(1.0))
+                    mult = jnp.where(f_kind == FAULT_BYZANTINE, -mult, mult)
+                    payload = jax.tree.map(lambda p: p * mult, payload)
+                    finite = jnp.asarray(True)
+                    for leaf in jax.tree.leaves(payload):
+                        finite = jnp.logical_and(finite,
+                                                 jnp.all(jnp.isfinite(leaf)))
+                    gnorm = _tree_global_norm(payload)
+                    # NaN gnorm compares False: a quarantined payload is never
+                    # also counted as clipped
+                    do_clip = jnp.logical_and(clip_norm > 0, gnorm > clip_norm)
+                    cscale = jnp.where(
+                        do_clip, clip_norm / jnp.maximum(gnorm, 1e-12),
+                        jnp.float32(1.0))
+                    payload = jax.tree.map(lambda p: p * cscale, payload)
+                    reject = tau_req > tau_max
+                    ok = jnp.logical_and(finite, jnp.logical_not(reject))
+                    proc = jnp.logical_and(any_alive, ok)
             else:
                 proc = any_alive
-            state, u, emit, lr_scale = agg.step(
-                carry["state"], Arrival(j, payload, t, tau))
-            emit = jnp.logical_and(emit, jnp.logical_and(t < T, proc))
+            with _stage("afl.commit"):
+                state, u, emit, lr_scale = agg.step(
+                    carry["state"], Arrival(j, payload, t, tau))
+                emit = jnp.logical_and(emit, jnp.logical_and(t < T, proc))
             # frozen events perform no aggregator transition on the host —
             # and neither do quarantined/rejected ones: the guarded select
             # keeps cache, running sums and the ACED owner-ring untouched
             # (jnp.where also stops any NaN from leaking out of the
             # unselected branch)
-            state = _select_tree(proc, state, carry["state"])
+            with _stage("afl.select"):
+                state = _select_tree(proc, state, carry["state"])
             n_upd_new = carry["n_upd"] + emit.astype(jnp.int32)
             if resync_every:
                 # periodic exact self-heal of the incremental running sums
@@ -659,13 +705,17 @@ def _staleness_program(*, grad_fn: Callable, params0,
                         s2 = agg.resync(s)
                         sanitize.check_resync_agreement(s, s2)
                         return s2
-                state = jax.lax.cond(
-                    jnp.logical_and(emit,
-                                    jnp.mod(n_upd_new, resync_every) == 0),
-                    resync_fn, lambda s: s, state)
-            eta = lr_of_t(t, lr) * lr_scale
-            w = apply_update(carry["w"], u, eta, emit)
-            ring, cursor = ap_ring(carry["ring"], carry["cursor"], w, emit)
+                with _stage("afl.resync"):
+                    state = jax.lax.cond(
+                        jnp.logical_and(
+                            emit, jnp.mod(n_upd_new, resync_every) == 0),
+                        resync_fn, lambda s: s, state)
+            with _stage("afl.update"):
+                eta = lr_of_t(t, lr) * lr_scale
+                w = apply_update(carry["w"], u, eta, emit)
+            with _stage("afl.ring"):
+                ring, cursor = ap_ring(carry["ring"], carry["cursor"], w,
+                                       emit)
             t_new = jnp.where(any_alive, t + emit.astype(jnp.int32), thaw_t)
             out = {"loss": loss, "emit": emit, "t": t,
                    "unorm": unorm(u), "alive": any_alive}
@@ -710,71 +760,81 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 g_row, traw_k, f_kind, f_scale = ev
             else:
                 g_row, traw_k = ev
-            g_row = shard(g_row, ("cache_clients",))
             t = carry["t"]
-            gone = jnp.logical_and(leave_at <= t, t < rejoin_at)
-            logits = jnp.where(gone, -jnp.inf, log_probs)
-            any_alive = jnp.any(~gone)
-            thaw_t = jnp.minimum(
-                jnp.min(jnp.where(gone, rejoin_at, NEVER)), T)
-            # Gumbel top-k: the K distinct clients of this tick, in sampling
-            # order (ties break to the lower index — the host reference
-            # mirrors with a stable argsort of the negated scores). Gone
-            # clients sink to -inf; with fewer than K alive their lanes are
-            # masked off below.
-            _, js = jax.lax.top_k(logits + g_row, k_batch)
-            js = js.astype(jnp.int32)
-            lane_alive = jnp.logical_not(gone[js])
-            tau_req = jnp.floor(traw_k).astype(jnp.int32)      # (K,)
+            with _stage("afl.sample"):
+                g_row = shard(g_row, ("cache_clients",))
+                gone = jnp.logical_and(leave_at <= t, t < rejoin_at)
+                logits = jnp.where(gone, -jnp.inf, log_probs)
+                any_alive = jnp.any(~gone)
+                thaw_t = jnp.minimum(
+                    jnp.min(jnp.where(gone, rejoin_at, NEVER)), T)
+                # Gumbel top-k: the K distinct clients of this tick, in
+                # sampling order (ties break to the lower index — the host
+                # reference mirrors with a stable argsort of the negated
+                # scores). Gone clients sink to -inf; with fewer than K alive
+                # their lanes are masked off below.
+                _, js = jax.lax.top_k(logits + g_row, k_batch)
+                js = js.astype(jnp.int32)
+                lane_alive = jnp.logical_not(gone[js])
+                tau_req = jnp.floor(traw_k).astype(jnp.int32)      # (K,)
+                if guards:
+                    tau_req = jnp.where(f_kind == FAULT_OVERSTALE, tau_max + 1,
+                                        tau_req)
+                taus = jnp.minimum(tau_req,
+                                   jnp.minimum(tau_max, carry["n_upd"]))
+            with _stage("afl.stale_read"):
+                w_stales = rd_rings(carry["ring"], carry["cursor"], taus)
+            with _stage("afl.client"):
+                # per-lane PRNG: keys[0] advances the carry chain, keys[1+i]
+                # seeds lane i's payload (the host reference splits
+                # identically; payload_fn's own internal splits stay per-lane
+                # deterministic)
+                keys = jax.random.split(carry["key"], k_batch + 1)
+                payloads, losses, _ = jax.vmap(payload_fn)(w_stales, js,
+                                                           keys[1:])
+                payloads = pin_payload(payloads)
             if guards:
-                tau_req = jnp.where(f_kind == FAULT_OVERSTALE, tau_max + 1,
-                                    tau_req)
-            taus = jnp.minimum(tau_req,
-                               jnp.minimum(tau_max, carry["n_upd"]))
-            w_stales = rd_rings(carry["ring"], carry["cursor"], taus)
-            # per-lane PRNG: keys[0] advances the carry chain, keys[1+i]
-            # seeds lane i's payload (the host reference splits identically;
-            # payload_fn's own internal splits stay per-lane deterministic)
-            keys = jax.random.split(carry["key"], k_batch + 1)
-            payloads, losses, _ = jax.vmap(payload_fn)(w_stales, js, keys[1:])
-            payloads = pin_payload(payloads)
-            if guards:
-                # the same multiplier chain as `step`, vectorized per lane —
-                # a faulty lane is quarantined/rejected individually and
-                # never vetoes its batch
-                mult = jnp.where(f_kind == FAULT_NAN, jnp.float32(jnp.nan),
-                                 jnp.float32(1.0))
-                mult = mult * jnp.where(f_kind == FAULT_EXPLODE, f_scale,
-                                        jnp.float32(1.0))
-                mult = jnp.where(f_kind == FAULT_BYZANTINE, -mult, mult)
-                payloads = jax.tree.map(
-                    lambda p: p * mult.reshape((-1,) + (1,) * (p.ndim - 1)),
-                    payloads)
-                finite = jnp.ones((k_batch,), jnp.bool_)
-                for leaf in jax.tree.leaves(payloads):
-                    finite = jnp.logical_and(
-                        finite, jnp.all(jnp.isfinite(leaf),
-                                        axis=tuple(range(1, leaf.ndim))))
-                gnorms = _tree_lane_norms(payloads)
-                do_clip = jnp.logical_and(clip_norm > 0, gnorms > clip_norm)
-                cscale = jnp.where(
-                    do_clip, clip_norm / jnp.maximum(gnorms, 1e-12),
-                    jnp.float32(1.0))
-                payloads = jax.tree.map(
-                    lambda p: p * cscale.reshape((-1,) + (1,) * (p.ndim - 1)),
-                    payloads)
-                reject = tau_req > tau_max
-                ok = jnp.logical_and(finite, jnp.logical_not(reject))
-                valid = jnp.logical_and(lane_alive, ok)
+                with _stage("afl.guards"):
+                    # the same multiplier chain as `step`, vectorized per
+                    # lane — a faulty lane is quarantined/rejected
+                    # individually and never vetoes its batch
+                    mult = jnp.where(f_kind == FAULT_NAN, jnp.float32(jnp.nan),
+                                     jnp.float32(1.0))
+                    mult = mult * jnp.where(f_kind == FAULT_EXPLODE, f_scale,
+                                            jnp.float32(1.0))
+                    mult = jnp.where(f_kind == FAULT_BYZANTINE, -mult, mult)
+                    payloads = jax.tree.map(
+                        lambda p: p * mult.reshape(
+                            (-1,) + (1,) * (p.ndim - 1)), payloads)
+                    finite = jnp.ones((k_batch,), jnp.bool_)
+                    for leaf in jax.tree.leaves(payloads):
+                        finite = jnp.logical_and(
+                            finite, jnp.all(jnp.isfinite(leaf),
+                                            axis=tuple(range(1, leaf.ndim))))
+                    gnorms = _tree_lane_norms(payloads)
+                    do_clip = jnp.logical_and(clip_norm > 0,
+                                              gnorms > clip_norm)
+                    cscale = jnp.where(
+                        do_clip, clip_norm / jnp.maximum(gnorms, 1e-12),
+                        jnp.float32(1.0))
+                    payloads = jax.tree.map(
+                        lambda p: p * cscale.reshape(
+                            (-1,) + (1,) * (p.ndim - 1)), payloads)
+                    reject = tau_req > tau_max
+                    ok = jnp.logical_and(finite, jnp.logical_not(reject))
+                    valid = jnp.logical_and(lane_alive, ok)
             else:
                 valid = lane_alive
-            # `proc` covers the all-gone freeze too: every lane dead ⇒ no
-            # transition, model/state held, t fast-forwards to the thaw
-            proc = jnp.any(valid)
-            state, u, agg_emit, lr_scale = agg.step_batch(
-                carry["state"], ArrivalBatch(js, payloads, t, taus, valid))
-            emit = jnp.logical_and(agg_emit, jnp.logical_and(t < T, proc))
-            state = _select_tree(proc, state, carry["state"])
+            with _stage("afl.commit"):
+                # `proc` covers the all-gone freeze too: every lane dead ⇒ no
+                # transition, model/state held, t fast-forwards to the thaw
+                proc = jnp.any(valid)
+                state, u, agg_emit, lr_scale = agg.step_batch(
+                    carry["state"], ArrivalBatch(js, payloads, t, taus, valid))
+                emit = jnp.logical_and(agg_emit,
+                                       jnp.logical_and(t < T, proc))
+            with _stage("afl.select"):
+                state = _select_tree(proc, state, carry["state"])
             n_upd_new = carry["n_upd"] + emit.astype(jnp.int32)
             if resync_every:
                 resync_fn = agg.resync
@@ -783,13 +843,17 @@ def _staleness_program(*, grad_fn: Callable, params0,
                         s2 = agg.resync(s)
                         sanitize.check_resync_agreement(s, s2)
                         return s2
-                state = jax.lax.cond(
-                    jnp.logical_and(emit,
-                                    jnp.mod(n_upd_new, resync_every) == 0),
-                    resync_fn, lambda s: s, state)
-            eta = lr_of_t(t, lr) * lr_scale
-            w = apply_update(carry["w"], u, eta, emit)
-            ring, cursor = ap_ring(carry["ring"], carry["cursor"], w, emit)
+                with _stage("afl.resync"):
+                    state = jax.lax.cond(
+                        jnp.logical_and(
+                            emit, jnp.mod(n_upd_new, resync_every) == 0),
+                        resync_fn, lambda s: s, state)
+            with _stage("afl.update"):
+                eta = lr_of_t(t, lr) * lr_scale
+                w = apply_update(carry["w"], u, eta, emit)
+            with _stage("afl.ring"):
+                ring, cursor = ap_ring(carry["ring"], carry["cursor"], w,
+                                       emit)
             t_new = jnp.where(any_alive, t + emit.astype(jnp.int32), thaw_t)
             nv = jnp.sum(valid.astype(jnp.float32))
             loss = (jnp.sum(jnp.where(valid, losses, 0.0))
@@ -964,6 +1028,39 @@ class ChunkedStalenessRunner:
     #: the chunked event slices must carry the matching tau_raw/fault lane
     #: axis — see `_staleness_program`
     k_batch: int = 1
+    #: the jitted program `chunk` calls (under `use_rules(mesh)` with a
+    #: mesh); `op_stages` lowers it, which the checkify build cannot
+    jit_chunk: Optional[Callable] = None
+
+    def op_stages(self, *args) -> Dict[str, str]:
+        """The stage map of the compiled chunk: each instruction name
+        (``fusion.729``, ``copy.257``, ``commit_batch.7``, …) mapped to the
+        outermost `STAGES` scope of its ``op_name`` metadata, or "" when it
+        has none — a profile of `chunk` names its ops by these instructions.
+
+        `args` are `chunk`'s arguments as arrays or `jax.ShapeDtypeStruct`s.
+        The chunk is lowered and compiled for them and nothing runs: lowering
+        reads only shapes, dtypes and shardings, so no buffer is read or
+        donated, a carry that `chunk` already consumed included. The
+        persistent compilation cache keys a program without its metadata
+        by default and may hand back an executable built with other scopes;
+        this compile keys it with its metadata, so the map is this program's.
+        The instructions are the same either way: metadata does not change
+        what XLA compiles.
+
+        A fusion carries the metadata of its root, so all the ops fused into
+        it count under the root's stage, even those traced in another
+        stage."""
+        key_flag = "jax_compilation_cache_include_metadata_in_key"
+        keyed = getattr(jax.config, key_flag)
+        jax.config.update(key_flag, True)
+        try:
+            with (use_rules(self.mesh) if self.mesh is not None
+                  else contextlib.nullcontext()):
+                text = self.jit_chunk.lower(*args).compile().as_text()
+        finally:
+            jax.config.update(key_flag, keyed)
+        return hlo_op_stages(text)
 
 
 def make_chunked_staleness_runner(*, mesh=None, **kwargs
@@ -997,7 +1094,7 @@ def make_chunked_staleness_runner(*, mesh=None, **kwargs
             lambda key, lr: jit_init(key, lr, w0), jit_chunk, marks, tau_max,
             kwargs.get("layout", "flat"), guards=guards,
             resync_every=resync_every, checkify_invariants=do_checkify,
-            k_batch=k_batch)
+            k_batch=k_batch, jit_chunk=jit_chunk)
 
     def init(key, lr):
         with use_rules(mesh):
@@ -1011,7 +1108,7 @@ def make_chunked_staleness_runner(*, mesh=None, **kwargs
                                   kwargs.get("layout", "flat"), mesh,
                                   guards=guards, resync_every=resync_every,
                                   checkify_invariants=do_checkify,
-                                  k_batch=k_batch)
+                                  k_batch=k_batch, jit_chunk=jit_chunk)
 
 
 def _window_slack(n_clients: int, rejoin_at, windows) -> int:

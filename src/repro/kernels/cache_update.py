@@ -66,5 +66,6 @@ def cache_row_update(u, g, c_row, old_scale, new_scale, inv_n, *,
         out_shape=[jax.ShapeDtypeStruct((dp,), jnp.float32),
                    jax.ShapeDtypeStruct((dp,), jnp.int8)],
         interpret=interpret,
+        name="cache_row_update",
     )(scalars, u, g, c_row)
     return u_new[:d], c_new[:d]

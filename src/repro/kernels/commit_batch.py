@@ -129,5 +129,8 @@ def commit_batch(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
                    jax.ShapeDtypeStruct((R, dp), jnp.float32),
                    jax.ShapeDtypeStruct((dp,), jnp.float32)],
         interpret=interpret,
+        # the compiled op (and its profile) is named after the kernel, not
+        # after whatever jit or scope wraps this call
+        name="commit_batch",
     )(lanes, mats, G, old_rows, V)
     return rows[:, :d], vecs_out[:, :d], upd[:d]

@@ -53,5 +53,6 @@ def masked_agg(cache, scales, mask, *, block_d: int = BLOCK_D,
         out_specs=pl.BlockSpec((block_d,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((dp,), jnp.float32),
         interpret=interpret,
+        name="masked_agg",
     )(w, cache)
     return out[:d]

@@ -57,6 +57,7 @@ def quantize_rows(x, *, block_d: int = BLOCK_D, interpret: bool | None = None):
         out_specs=pl.BlockSpec((n,), lambda r, i: (r,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         interpret=interpret,
+        name="quantize_rows_absmax",
     )(xp)
     scales = jnp.maximum(absmax, 1e-12) / INT8_MAX
     q = pl.pallas_call(
@@ -67,6 +68,7 @@ def quantize_rows(x, *, block_d: int = BLOCK_D, interpret: bool | None = None):
         out_specs=pl.BlockSpec((n, block_d), lambda r, i: (r, i)),
         out_shape=jax.ShapeDtypeStruct((n, dp), jnp.int8),
         interpret=interpret,
+        name="quantize_rows",
     )(xp, scales)
     return q[:, :d], scales
 
@@ -88,5 +90,6 @@ def dequantize_rows(q, scales, *, block_d: int = BLOCK_D,
         out_specs=pl.BlockSpec((n, block_d), lambda r, i: (r, i)),
         out_shape=jax.ShapeDtypeStruct((n, dp), jnp.float32),
         interpret=interpret,
+        name="dequantize_rows",
     )(qp, scales)
     return x[:, :d]

@@ -65,5 +65,6 @@ def row_delta(g, c_row, old_scale, new_scale, *,
         out_shape=[jax.ShapeDtypeStruct((dp,), jnp.float32),
                    jax.ShapeDtypeStruct((dp,), jnp.int8)],
         interpret=interpret,
+        name="row_delta",
     )(scalars, g, c_row)
     return delta[:d], c_new[:d]

@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import restore_train_checkpoint, save_train_checkpoint
 from repro.configs.registry import afl_config, get_config
@@ -292,25 +293,32 @@ def _train(args, compile_s) -> TrainResult:
         if guards:
             guard_args = (faults.kind[lo:hi], faults.scale[lo:hi],
                           jnp.float32(args.clip_norm))
-        carry, outs = runner.chunk(carry, rand.gumbels[lo:hi],
-                                   rand.tau_raw[lo:hi], rand.leave_at,
-                                   rand.rejoin_at, lr0, *guard_args)
-        em = np.asarray(outs["emit"])
-        losses.extend(np.asarray(outs["loss"])[em].tolist())
+        # host spans on the profiler's clock, each with the chunk's event
+        # offset: under a caller's `jax.profiler.trace`, every device idle
+        # gap falls in a dispatch, a readback, a checkpoint save or a log
+        with TraceAnnotation("afl.dispatch", lo=lo):
+            carry, outs = runner.chunk(carry, rand.gumbels[lo:hi],
+                                       rand.tau_raw[lo:hi], rand.leave_at,
+                                       rand.rejoin_at, lr0, *guard_args)
+        with TraceAnnotation("afl.readback", lo=lo):
+            em = np.asarray(outs["emit"])
+            losses.extend(np.asarray(outs["loss"])[em].tolist())
+            t_now = int(carry["t"])
         events_done += hi - lo
-        t_now = int(carry["t"])
         if steady is None:
             steady = (time.time(), events_done)
         if len(losses) - last_log >= args.log_every or hi >= n_events:
-            last_log = len(losses)
-            dt = time.time() - t0
-            print(f"t={t_now:5d}/{T} events={hi} "
-                  f"loss={np.mean(losses[-args.log_every:]):.4f} "
-                  f"({events_done * args.k_batch / max(dt, 1e-9):.1f} ev/s)",
-                  flush=True)
+            with TraceAnnotation("afl.log", lo=lo):
+                last_log = len(losses)
+                dt = time.time() - t0
+                print(f"t={t_now:5d}/{T} events={hi} "
+                      f"loss={np.mean(losses[-args.log_every:]):.4f} "
+                      f"({events_done * args.k_batch / max(dt, 1e-9):.1f}"
+                      " ev/s)", flush=True)
         if args.ckpt_dir and (hi // args.ckpt_every != lo // args.ckpt_every
                               or hi >= n_events or t_now >= T):
-            save_train_checkpoint(args.ckpt_dir, hi, carry)
+            with TraceAnnotation("afl.checkpoint", lo=lo):
+                save_train_checkpoint(args.ckpt_dir, hi, carry)
         if t_now >= T:
             break
 

@@ -250,10 +250,18 @@ class Aggregator:
         """K-arrival transition: -> (state, update, emit, lr_scale) — one
         aggregation and one emission decision for the whole batch. Same
         trace-safety contract as `step`; invalid lanes must be perfect
-        no-ops. A batch with zero valid lanes must leave `state` unchanged
-        and gate `emit` off. `step` with a singleton batch is the K=1
-        sanity anchor, but the engines never call `step_batch` at K=1 —
-        that path stays on `step` verbatim for bit-identity."""
+        no-ops. `step` with a singleton batch is the K=1 sanity anchor, but
+        the engines never call `step_batch` at K=1 — that path stays on
+        `step` verbatim for bit-identity.
+
+        The K-batched scan tick relies on this for the per-client cache:
+        lane validity is its only gate. The cache must be written only
+        through lane-masked row writes that put each invalid lane's stored
+        row and scale back bit for bit, NaN payloads included, so a batch
+        with zero valid lanes leaves it bit-identical. The engine holds
+        every other leaf of `state` and gates `emit` off on such a tick
+        itself (`scan_staleness._select_batch_state`): ACED's expiry sweep,
+        for one, still moves its running sums there."""
         raise NotImplementedError(
             f"{type(self).__name__} does not support K-batched arrivals")
 

@@ -98,7 +98,8 @@ from jax.flatten_util import ravel_pytree
 from repro.core import sanitize
 from repro.core.aggregators import (Aggregator, Arrival, ArrivalBatch,
                                     wants_cache_init)
-from repro.core.cache import (init_tree_cache, tree_cache_row,
+from repro.core.cache import (FlatCache, init_tree_cache,
+                              is_tree_cache_leaf, tree_cache_row,
                               tree_cache_rows, tree_cache_set_row)
 from repro.core.scan_engine import (ScanResult, _payload_chain, _to_result,
                                     default_n_events)
@@ -357,6 +358,30 @@ def _select_tree(pred, new, old):
     all-gone freezes so a thawed run continues from the frozen state exactly
     like the host loop (which performs no transitions while frozen)."""
     return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, old)
+
+
+def _is_cache(x) -> bool:
+    """A per-client cache in the aggregator state: a `FlatCache`, or one
+    tree-cache leaf (`cache.is_tree_cache_leaf`)."""
+    return isinstance(x, FlatCache) or is_tree_cache_leaf(x)
+
+
+def _select_batch_state(pred, new, old):
+    """`_select_tree` for the K-batched tick, with the per-client caches
+    passed through as `Aggregator.step_batch` returned them.
+
+    At K > 1 lane validity is the only gate on the cache: `step_batch`
+    writes it lane by lane, every invalid lane writing its stored row and
+    scale back bit for bit, so on a tick with no valid lane the returned
+    cache already equals `old`. Selecting over it anyway reads and writes
+    the whole O(n·d) cache every tick, and — reading the old cache after
+    the row writes — makes XLA copy the loop carry instead of updating it
+    in place. Every other leaf (the O(n + d) running sums, counters and
+    ACED's owner-ring) keeps the select, and with it the freeze and NaN
+    protection."""
+    return jax.tree.map(
+        lambda a, b: a if _is_cache(a) else jnp.where(pred, a, b),
+        new, old, is_leaf=_is_cache)
 
 
 def _tree_global_norm(tree):
@@ -828,13 +853,14 @@ def _staleness_program(*, grad_fn: Callable, params0,
             with _stage("afl.commit"):
                 # `proc` covers the all-gone freeze too: every lane dead ⇒ no
                 # transition, model/state held, t fast-forwards to the thaw
+                # (the cache by its invalid lanes, the rest by the select)
                 proc = jnp.any(valid)
                 state, u, agg_emit, lr_scale = agg.step_batch(
                     carry["state"], ArrivalBatch(js, payloads, t, taus, valid))
                 emit = jnp.logical_and(agg_emit,
                                        jnp.logical_and(t < T, proc))
             with _stage("afl.select"):
-                state = _select_tree(proc, state, carry["state"])
+                state = _select_batch_state(proc, state, carry["state"])
             n_upd_new = carry["n_upd"] + emit.astype(jnp.int32)
             if resync_every:
                 resync_fn = agg.resync

@@ -49,40 +49,49 @@ def _quad_eval_fn(params):
 def _host_and_scan(algo, *, n=8, d=6, T=40, beta=2.0, seed=0, tau_max=None,
                    speed_skew=0.0, dropout_frac=0.0, dropout_at=None,
                    rejoin_at=None, windows=None, eval_every=None,
-                   server_lr=0.05):
+                   server_lr=0.05, k_batch=1):
     """Run host (replay mode) and scan on the same random stream."""
     grad_fn = quad_grad_fn(n, d)
-    n_events = default_n_events(AGGS[algo](), T)
+
+    def agg():
+        if algo == "aced" and k_batch > 1:
+            return ACED(tau_algo=5, max_cohort=k_batch)
+        return AGGS[algo]()
+    n_events = default_n_events(agg(), T)
     if rejoin_at is not None or windows is not None:
         n_events += n                       # freeze fast-forward slack
     rand = build_staleness_randomness(seed, n_events, n, beta, dropout_frac,
                                       speed_skew, dropout_at=dropout_at,
-                                      rejoin_at=rejoin_at, windows=windows)
+                                      rejoin_at=rejoin_at, windows=windows,
+                                      k_batch=k_batch)
     eval_fn = _quad_eval_fn if eval_every else None
     sim = StalenessSimulator(
-        grad_fn=grad_fn, params0=jnp.zeros(d), aggregator=AGGS[algo](),
+        grad_fn=grad_fn, params0=jnp.zeros(d), aggregator=agg(),
         n_clients=n, server_lr=server_lr, beta=beta, tau_max=tau_max,
         speed_skew=speed_skew, dropout_frac=dropout_frac,
         dropout_at=dropout_at, rejoin_at=rejoin_at, windows=windows,
-        eval_fn=eval_fn, eval_every=eval_every or T, seed=seed, replay=rand)
+        eval_fn=eval_fn, eval_every=eval_every or T, seed=seed, replay=rand,
+        k_batch=k_batch)
     hr = sim.run(T)
     sr = run_staleness_scan(
-        grad_fn=grad_fn, params0=jnp.zeros(d), aggregator=AGGS[algo](),
+        grad_fn=grad_fn, params0=jnp.zeros(d), aggregator=agg(),
         n_clients=n, server_lr=server_lr, T=T, beta=beta, tau_max=tau_max,
         speed_skew=speed_skew, dropout_frac=dropout_frac,
         dropout_at=dropout_at, rejoin_at=rejoin_at, windows=windows,
-        eval_fn=eval_fn, eval_every=eval_every, seed=seed)
+        eval_fn=eval_fn, eval_every=eval_every, seed=seed, k_batch=k_batch,
+        n_events=n_events if k_batch > 1 else None)
     return sim, hr, sr
 
 
-def _assert_equivalent(sim, hr, sr):
+def _assert_equivalent(sim, hr, sr, comms=True):
     assert np.max(np.abs(sr.w - np.asarray(sim.w))) <= 1e-5
     assert len(sr.losses) == len(hr.losses)
     np.testing.assert_allclose(sr.losses, hr.losses, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(sr.update_norms, hr.update_norms,
                                rtol=1e-4, atol=1e-5)
     assert sr.ts.tolist() == hr.ts
-    assert sr.total_comms == hr.total_comms
+    if comms:
+        assert sr.total_comms == hr.total_comms
     assert sr.eval_ts == hr.eval_ts
     for se, he in zip(sr.evals, hr.evals):
         assert set(se) == set(he)
@@ -196,6 +205,25 @@ def test_staleness_scan_freeze_thaw_all_left(algo):
     assert not [t for t in hr.ts if 12 < t < 22]
     if hr.ts:                               # the run resumes after the thaw
         assert max(hr.ts) >= 22
+
+
+@pytest.mark.parametrize("algo", ["ace", "aced", "ca2fl"])
+def test_staleness_scan_freeze_thaw_all_left_k4(algo):
+    """The freeze at K = 4 arrivals a tick: a frozen tick has no valid lane,
+    so the per-client cache is held by its lanes alone and every other
+    leaf by the tick's select — event for event matched to the host K-batch
+    reference's jump."""
+    n, T = 8, 50
+    leave = np.full(n, 12, np.int64)
+    rejoin = np.full(n, 22, np.int64)
+    rejoin[3] = 30                          # one client stays away longer
+    sim, hr, sr = _host_and_scan(algo, n=n, T=T, windows=(leave, rejoin),
+                                 eval_every=10, k_batch=4)
+    # at K > 1 the scan counts a tick as one communication, the host each
+    # live lane
+    _assert_equivalent(sim, hr, sr, comms=False)
+    assert not [t for t in hr.ts if 12 < t < 22]
+    assert max(hr.ts) >= 22
 
 
 def test_staleness_scan_legacy_rejoin_scalar():

@@ -82,6 +82,10 @@ class Arrival(NamedTuple):
     payload: Any                # gradient-like descent direction: (d,) or pytree
     t: int                      # server iteration counter
     staleness: int              # server iterations since client got its model
+    #: bool []: False for an arrival the scan tick does not process (frozen,
+    #: quarantined or refused) — the rule must then leave the per-client
+    #: cache bit-exact (see `Aggregator.step`)
+    valid: Any = True
 
 
 class ArrivalBatch(NamedTuple):
@@ -236,7 +240,15 @@ class Aggregator:
 
         Must be trace-safe: no Python branching on traced values, no
         device→host syncs. `update` is always a (d,) array; when `emit`
-        is False its value is ignored by the caller."""
+        is False its value is ignored by the caller.
+
+        The per-arrival scan tick relies on `arr.valid` for the per-client
+        cache: it is the cache's only gate. A rule must write the cache only
+        through validity-masked row writes (`cache_set_row_delta` /
+        `cache_set_row` with `valid`), which put the stored row and scale
+        back bit for bit, NaN payloads included, when `arr.valid` is False.
+        The engine holds every other leaf of `state` and gates `emit` off on
+        such a tick itself (`scan_staleness._select_state`)."""
         raise NotImplementedError
 
     def on_arrival(self, state, arr: Arrival):
@@ -250,18 +262,18 @@ class Aggregator:
         """K-arrival transition: -> (state, update, emit, lr_scale) — one
         aggregation and one emission decision for the whole batch. Same
         trace-safety contract as `step`; invalid lanes must be perfect
-        no-ops. `step` with a singleton batch is the K=1 sanity anchor, but
-        the engines never call `step_batch` at K=1 — that path stays on
-        `step` verbatim for bit-identity.
+        no-ops. `step` with a singleton batch is the K=1 sanity anchor; the
+        engines run K=1 ticks on `step`.
 
         The K-batched scan tick relies on this for the per-client cache:
-        lane validity is its only gate. The cache must be written only
-        through lane-masked row writes that put each invalid lane's stored
-        row and scale back bit for bit, NaN payloads included, so a batch
-        with zero valid lanes leaves it bit-identical. The engine holds
-        every other leaf of `state` and gates `emit` off on such a tick
-        itself (`scan_staleness._select_batch_state`): ACED's expiry sweep,
-        for one, still moves its running sums there."""
+        lane validity is its only gate, as `arr.valid` is `step`'s. The
+        cache must be written only through lane-masked row writes that put
+        each invalid lane's stored row and scale back bit for bit, NaN
+        payloads included, so a batch with zero valid lanes leaves it
+        bit-identical. The engine holds every other leaf of `state` and
+        gates `emit` off on such a tick itself
+        (`scan_staleness._select_state`): ACED's expiry sweep, for one,
+        still moves its running sums there."""
         raise NotImplementedError(
             f"{type(self).__name__} does not support K-batched arrivals")
 
@@ -417,7 +429,8 @@ class CA2FL(Aggregator):
 
     def step(self, state, arr):
         j = jnp.asarray(arr.client, jnp.int32)
-        h, delta, old = cache_set_row_delta(state["h"], j, arr.payload)
+        h, delta, old = cache_set_row_delta(state["h"], j, arr.payload,
+                                            arr.valid)
         accum = _acc(state["accum"],
                      jax.tree.map(lambda g, o: g.astype(jnp.float32) - o,
                                   arr.payload, old))
@@ -531,7 +544,7 @@ class CA2FLDirect(Aggregator):
         accum = _acc(state["accum"],
                      jax.tree.map(lambda g, o: g.astype(jnp.float32) - o,
                                   arr.payload, old))
-        h = cache_set_row(state["h"], j, arr.payload)
+        h = cache_set_row(state["h"], j, arr.payload, arr.valid)
         count = state["count"] + 1
         emit = count >= self.buffer_size
         cf = count.astype(jnp.float32)
@@ -560,7 +573,8 @@ class ACEDirect(Aggregator):
         return {"cache": _init_cache(n, d, self.cache_dtype, init_grads)}
 
     def step(self, state, arr):
-        cache = cache_set_row(state["cache"], arr.client, arr.payload)
+        cache = cache_set_row(state["cache"], arr.client, arr.payload,
+                              arr.valid)
         return {"cache": cache}, cache_mean(cache), _TRUE, _ONE
 
 
@@ -597,18 +611,20 @@ class ACEIncremental(Aggregator):
             u, q_row = kernel_ops.cache_row_update(
                 u, arr.payload, c_row, old_scale, new_scale, 1.0 / cache.n)
             cache = FlatCache(
-                jax.lax.dynamic_update_index_in_dim(cache.data, q_row, j, 0),
                 jax.lax.dynamic_update_index_in_dim(
-                    cache.scale, new_scale.astype(jnp.float32), j, 0))
+                    cache.data, jnp.where(arr.valid, q_row, c_row), j, 0),
+                jax.lax.dynamic_update_index_in_dim(
+                    cache.scale, jnp.where(arr.valid,
+                                           new_scale.astype(jnp.float32),
+                                           old_scale), j, 0))
         else:
             n = cache_n(cache)
-            old = cache_row(cache, j)
-            cache = cache_set_row(cache, j, arr.payload)
-            new = cache_row(cache, j)
+            cache, delta, _old = cache_set_row_delta(cache, j, arr.payload,
+                                                     arr.valid)
             u = jax.tree.map(
-                lambda u_, nw, od: (u_.astype(jnp.float32)
-                                    + (nw - od) / n).astype(u_.dtype),
-                u, new, old)
+                lambda u_, d_: (u_.astype(jnp.float32)
+                                + d_ / n).astype(u_.dtype),
+                u, delta)
         return {"cache": cache, "u": u}, u, _TRUE, _ONE
 
     def step_batch(self, state, batch):
@@ -721,7 +737,7 @@ class ACED(Aggregator):
                 payloads=jax.tree.map(lambda g: g[None], arr.payload),
                 t=arr.t,
                 staleness=jnp.asarray(arr.staleness, jnp.int32)[None],
-                valid=jnp.ones((1,), jnp.bool_)))
+                valid=jnp.asarray(arr.valid, jnp.bool_)[None]))
         j = jnp.asarray(arr.client, jnp.int32)
         t = jnp.asarray(arr.t, jnp.int32)
         tau, P = self.tau_algo, self.ring_size
@@ -776,7 +792,8 @@ class ACED(Aggregator):
         old_ts = t_start[j]
         was_active = old_ts >= t - tau
         was_init = init_mask[j]
-        cache, delta, old = cache_set_row_delta(cache, j, arr.payload)
+        cache, delta, old = cache_set_row_delta(cache, j, arr.payload,
+                                                arr.valid)
         g_dead = dead.astype(jnp.float32)
         g_fire = fire.astype(jnp.float32)
         g_ret = 1.0 - was_active.astype(jnp.float32)   # returning client
@@ -975,7 +992,7 @@ class ACEDDirect(Aggregator):
 
     def step(self, state, arr):
         j = jnp.asarray(arr.client, jnp.int32)
-        cache = cache_set_row(state["cache"], j, arr.payload)
+        cache = cache_set_row(state["cache"], j, arr.payload, arr.valid)
         t = jnp.asarray(arr.t, jnp.int32)
         t_start = jax.lax.dynamic_update_index_in_dim(
             state["t_start"], t + 1, j, 0)

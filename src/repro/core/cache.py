@@ -69,50 +69,68 @@ class FlatCache(NamedTuple):
             return r.astype(jnp.float32) * s
         return r.astype(jnp.float32)
 
-    def set_row(self, i, g):
+    def set_row(self, i, g, valid=True):
+        """Write row i ← g where `valid`; where not, write the stored row
+        and scale back bit for bit (a NaN `g` included)."""
         i = jnp.asarray(i, jnp.int32)
+        old_raw = jax.lax.dynamic_index_in_dim(self.data, i, keepdims=False)
         if self.data.dtype == jnp.int8:
             q, s = quantize_rows(g)
+            old_s = jax.lax.dynamic_index_in_dim(self.scale, i,
+                                                 keepdims=False)
             return FlatCache(
-                shard(jax.lax.dynamic_update_index_in_dim(self.data, q, i, 0),
-                      ("cache_clients", "cache_d")),
-                shard(jax.lax.dynamic_update_index_in_dim(self.scale, s, i, 0),
-                      ("cache_clients",)))
+                shard(jax.lax.dynamic_update_index_in_dim(
+                    self.data, jnp.where(valid, q, old_raw), i, 0),
+                    ("cache_clients", "cache_d")),
+                shard(jax.lax.dynamic_update_index_in_dim(
+                    self.scale, jnp.where(valid, s, old_s), i, 0),
+                    ("cache_clients",)))
         return FlatCache(
             shard(jax.lax.dynamic_update_index_in_dim(
-                self.data, g.astype(self.data.dtype), i, 0),
+                self.data,
+                jnp.where(valid, g.astype(self.data.dtype), old_raw), i, 0),
                 ("cache_clients", "cache_d")),
             self.scale)
 
-    def set_row_delta(self, i, g):
+    def set_row_delta(self, i, g, valid=True):
         """Write row i and return ``(cache', delta, old)`` where
         ``old = dq(row_i)`` before the write and ``delta = dq(row_i') − old``
         — the exact change a running sum of dequantized rows sees. The int8
         path routes through the fused `row_delta` kernel dispatch (one HBM
         pass: dequantize-old + quantize-new + delta); float paths are a read
-        + write. Row outputs keep the feature sharding (``cache_d``)."""
+        + write. Row outputs keep the feature sharding (``cache_d``). Where
+        not `valid` the stored row and scale are written back bit for bit (a
+        NaN `g` included) and `delta` is zero, as in `set_rows_delta`."""
         i = jnp.asarray(i, jnp.int32)
+        c_row = jax.lax.dynamic_index_in_dim(self.data, i, keepdims=False)
         if self.data.dtype == jnp.int8:
-            c_row = jax.lax.dynamic_index_in_dim(self.data, i, keepdims=False)
             old_scale = jax.lax.dynamic_index_in_dim(self.scale, i,
                                                      keepdims=False)
             new_scale = kernel_ref.row_scale(g)
             delta, q = kernel_ops.row_delta(g, c_row, old_scale, new_scale)
             cache = FlatCache(
-                shard(jax.lax.dynamic_update_index_in_dim(self.data, q, i, 0),
-                      ("cache_clients", "cache_d")),
                 shard(jax.lax.dynamic_update_index_in_dim(
-                    self.scale, new_scale.astype(jnp.float32), i, 0),
+                    self.data, jnp.where(valid, q, c_row), i, 0),
+                    ("cache_clients", "cache_d")),
+                shard(jax.lax.dynamic_update_index_in_dim(
+                    self.scale, jnp.where(valid, new_scale.astype(jnp.float32),
+                                          old_scale), i, 0),
                     ("cache_clients",)))
             # dequantize the old row directly — reconstructing it as
             # q·new_scale − delta would cancel catastrophically when the
             # client's successive gradients differ by orders of magnitude
             old = c_row.astype(jnp.float32) * old_scale
-            return cache, shard(delta, ("cache_d",)), shard(old, ("cache_d",))
-        old = self.row(i)
-        cache = self.set_row(i, g)
-        new = g.astype(self.data.dtype).astype(jnp.float32)
-        return cache, shard(new - old, ("cache_d",)), shard(old, ("cache_d",))
+            return (cache, shard(jnp.where(valid, delta, 0.0), ("cache_d",)),
+                    shard(old, ("cache_d",)))
+        old = c_row.astype(jnp.float32)
+        new_raw = g.astype(self.data.dtype)
+        cache = FlatCache(
+            shard(jax.lax.dynamic_update_index_in_dim(
+                self.data, jnp.where(valid, new_raw, c_row), i, 0),
+                ("cache_clients", "cache_d")),
+            self.scale)
+        delta = jnp.where(valid, new_raw.astype(jnp.float32) - old, 0.0)
+        return cache, shard(delta, ("cache_d",)), shard(old, ("cache_d",))
 
     def rows(self, idx):
         """Dequantized f32 gather of rows ``idx`` (K,) — the batched read
@@ -297,24 +315,11 @@ def tree_cache_row(cache, i):
     return jax.tree.map(leaf, cache, is_leaf=lambda x: isinstance(x, dict) and "q" in x)
 
 
-def tree_cache_set_row(cache, i, grads):
-    def leaf(c, g):
-        if c["q"].dtype == jnp.int8:
-            # axis-preserving scale reduction: flattening (reshape(-1)) would
-            # destroy the leaf's 2-D (data, model) sharding and force XLA to
-            # all-gather the full gradient — ~2x params of ICI traffic per
-            # step at 405B scale (see EXPERIMENTS.md §Perf iteration 1).
-            s = jnp.maximum(jnp.max(jnp.abs(g.astype(jnp.float32))), 1e-12) \
-                / INT8_MAX
-            q = jnp.clip(jnp.round(g.astype(jnp.float32) / s), -127, 127
-                         ).astype(jnp.int8)
-            return {"q": jax.lax.dynamic_update_index_in_dim(c["q"], q, i, 0),
-                    "scale": jax.lax.dynamic_update_index_in_dim(
-                        c["scale"], s.astype(jnp.float32), i, 0)}
-        return {"q": jax.lax.dynamic_update_index_in_dim(
-                    c["q"], g.astype(c["q"].dtype), i, 0)}
-    return jax.tree.map(leaf, cache, grads,
-                        is_leaf=lambda x: isinstance(x, dict) and "q" in x)
+def tree_cache_set_row(cache, i, grads, valid=True):
+    """Write row i ← `grads` where `valid` (one per-leaf scalar scale under
+    int8); where not, the stored row and scale are written back bit for
+    bit. The write of `tree_cache_set_row_delta`, its delta unused."""
+    return tree_cache_set_row_delta(cache, i, grads, valid)[0]
 
 
 def tree_cache_rows(cache, idx):
@@ -382,16 +387,50 @@ def tree_cache_set_rows_delta(cache, idx, grads,  # tracecheck: ignore[TRC004]
             jax.tree.unflatten(treedef, olds))
 
 
-def tree_cache_set_row_delta(cache, i, grads):
+def tree_cache_set_row_delta(cache, i, grads, valid=True):
     """Tree-cache analogue of `FlatCache.set_row_delta`: returns
     ``(cache', delta, old)`` with `delta`/`old` grads-like f32 pytrees.
     Per-leaf generic path (the pjit train step fuses these elementwise ops
-    itself; the Pallas fusion targets the flat scan layout)."""
-    old = tree_cache_row(cache, i)
-    new_cache = tree_cache_set_row(cache, i, grads)
-    new = tree_cache_row(new_cache, i)
-    delta = jax.tree.map(lambda a, b: a - b, new, old)
-    return new_cache, delta, old
+    itself; the Pallas fusion targets the flat scan layout). The new row's
+    dequantized value comes from the quantized payload, not from a read of
+    the written cache. Where not `valid` each leaf writes its stored row
+    and scale back bit for bit (a NaN payload included) and zeroes its
+    `delta`."""
+    deltas, olds = [], []
+
+    def leaf(c, g):
+        g = g.astype(jnp.float32)
+        old_raw = jax.lax.dynamic_index_in_dim(c["q"], i, keepdims=False)
+        if c["q"].dtype == jnp.int8:
+            old_s = jax.lax.dynamic_index_in_dim(c["scale"], i,
+                                                 keepdims=False)
+            old = old_raw.astype(jnp.float32) * old_s
+            # axis-preserving scale reduction: flattening (reshape(-1))
+            # would destroy the leaf's 2-D (data, model) sharding and force
+            # XLA to all-gather the full gradient — ~2x params of ICI
+            # traffic per step at 405B scale (see EXPERIMENTS.md §Perf
+            # iteration 1).
+            s = jnp.maximum(jnp.max(jnp.abs(g)), 1e-12) / INT8_MAX
+            q = jnp.clip(jnp.round(g / s), -127, 127).astype(jnp.int8)
+            new = q.astype(jnp.float32) * s
+            out = {"q": jax.lax.dynamic_update_index_in_dim(
+                       c["q"], jnp.where(valid, q, old_raw), i, 0),
+                   "scale": jax.lax.dynamic_update_index_in_dim(
+                       c["scale"], jnp.where(valid, s, old_s), i, 0)}
+        else:
+            old = old_raw.astype(jnp.float32)
+            new_raw = g.astype(c["q"].dtype)
+            new = new_raw.astype(jnp.float32)
+            out = {"q": jax.lax.dynamic_update_index_in_dim(
+                c["q"], jnp.where(valid, new_raw, old_raw), i, 0)}
+        deltas.append(jnp.where(valid, new - old, 0.0))
+        olds.append(old)
+        return out
+
+    new_cache = jax.tree.map(leaf, cache, grads, is_leaf=is_tree_cache_leaf)
+    treedef = jax.tree.structure(grads)
+    return (new_cache, jax.tree.unflatten(treedef, deltas),
+            jax.tree.unflatten(treedef, olds))
 
 
 def tree_cache_mean(cache, mask=None):
@@ -447,22 +486,25 @@ def cache_rows(cache, idx):
     return tree_cache_rows(cache, idx)
 
 
-def cache_set_row(cache, i, g):
-    """Write (re-quantizing as needed) row i; returns the same layout."""
+def cache_set_row(cache, i, g, valid=True):
+    """Write (re-quantizing as needed) row i where `valid`; an invalid write
+    leaves the stored row and scale bit-exact. Returns the same layout."""
     if isinstance(cache, FlatCache):
-        return cache.set_row(i, g)
-    return tree_cache_set_row(cache, i, g)
+        return cache.set_row(i, g, valid)
+    return tree_cache_set_row(cache, i, g, valid)
 
 
-def cache_set_row_delta(cache, i, g):
+def cache_set_row_delta(cache, i, g, valid=True):
     """Write row i, returning ``(cache', delta, old)`` — the running-sum
     primitive behind the O(d) server rules: ``delta = dq(new) − dq(old)``
     folds into an incremental aggregate (ACED's active-set sum, CA²FL's
     h_sum) and ``old`` is exactly the dequantized value previously added, so
-    those aggregates stay exact under int8 (paper Alg. a.5 invariant)."""
+    those aggregates stay exact under int8 (paper Alg. a.5 invariant).
+    Where not `valid` the stored row and scale stay bit-exact and `delta`
+    is zero — the single-row form of `cache_set_rows_delta`'s lane mask."""
     if isinstance(cache, FlatCache):
-        return cache.set_row_delta(i, g)
-    return tree_cache_set_row_delta(cache, i, g)
+        return cache.set_row_delta(i, g, valid)
+    return tree_cache_set_row_delta(cache, i, g, valid)
 
 
 def cache_set_rows_delta(cache, idx, G, valid=None):

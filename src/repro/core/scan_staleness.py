@@ -353,32 +353,30 @@ def _apply_evals(snaps, hits, marks, eval_fn, unravel):
     return evals, eval_ts
 
 
-def _select_tree(pred, new, old):
-    """Per-leaf ``where(pred, new, old)`` — gates aggregator state during
-    all-gone freezes so a thawed run continues from the frozen state exactly
-    like the host loop (which performs no transitions while frozen)."""
-    return jax.tree.map(lambda a, b: jnp.where(pred, a, b), new, old)
-
-
 def _is_cache(x) -> bool:
     """A per-client cache in the aggregator state: a `FlatCache`, or one
     tree-cache leaf (`cache.is_tree_cache_leaf`)."""
     return isinstance(x, FlatCache) or is_tree_cache_leaf(x)
 
 
-def _select_batch_state(pred, new, old):
-    """`_select_tree` for the K-batched tick, with the per-client caches
-    passed through as `Aggregator.step_batch` returned them.
+def _select_state(pred, new, old):
+    """The tick's state gate: per-leaf ``where(pred, new, old)`` over the
+    aggregator state, with the per-client caches passed through as the
+    rule's transition returned them. It holds the state through all-gone
+    freezes, so a thawed run continues from the frozen state exactly like
+    the host loop (which performs no transitions while frozen), and
+    through quarantined or refused arrivals.
 
-    At K > 1 lane validity is the only gate on the cache: `step_batch`
-    writes it lane by lane, every invalid lane writing its stored row and
-    scale back bit for bit, so on a tick with no valid lane the returned
-    cache already equals `old`. Selecting over it anyway reads and writes
-    the whole O(n·d) cache every tick, and — reading the old cache after
-    the row writes — makes XLA copy the loop carry instead of updating it
-    in place. Every other leaf (the O(n + d) running sums, counters and
-    ACED's owner-ring) keeps the select, and with it the freeze and NaN
-    protection."""
+    Validity is the only gate on the cache, on both ticks: `Aggregator.step`
+    gets `pred` as `Arrival.valid`, `step_batch` the lanes' validity, and
+    each writes the cache only through masked row writes that put the
+    stored row and scale back bit for bit, so on a tick that `pred` refuses
+    the returned cache already equals `old`. Selecting over it anyway reads
+    and writes the whole O(n·d) cache every tick, and — reading the old
+    cache after the row write — makes XLA copy the loop carry instead of
+    updating it in place. Every other leaf (the O(n + d) running sums,
+    counters and ACED's owner-ring) keeps the select, and with it the freeze
+    and NaN protection."""
     return jax.tree.map(
         lambda a, b: a if _is_cache(a) else jnp.where(pred, a, b),
         new, old, is_leaf=_is_cache)
@@ -711,15 +709,15 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 proc = any_alive
             with _stage("afl.commit"):
                 state, u, emit, lr_scale = agg.step(
-                    carry["state"], Arrival(j, payload, t, tau))
+                    carry["state"], Arrival(j, payload, t, tau, proc))
                 emit = jnp.logical_and(emit, jnp.logical_and(t < T, proc))
             # frozen events perform no aggregator transition on the host —
-            # and neither do quarantined/rejected ones: the guarded select
-            # keeps cache, running sums and the ACED owner-ring untouched
-            # (jnp.where also stops any NaN from leaking out of the
-            # unselected branch)
+            # and neither do quarantined/rejected ones: the masked row write
+            # keeps the cache, the select the running sums and the ACED
+            # owner-ring untouched (jnp.where also stops any NaN from
+            # leaking out of the unselected branch)
             with _stage("afl.select"):
-                state = _select_tree(proc, state, carry["state"])
+                state = _select_state(proc, state, carry["state"])
             n_upd_new = carry["n_upd"] + emit.astype(jnp.int32)
             if resync_every:
                 # periodic exact self-heal of the incremental running sums
@@ -860,7 +858,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 emit = jnp.logical_and(agg_emit,
                                        jnp.logical_and(t < T, proc))
             with _stage("afl.select"):
-                state = _select_batch_state(proc, state, carry["state"])
+                state = _select_state(proc, state, carry["state"])
             n_upd_new = carry["n_upd"] + emit.astype(jnp.int32)
             if resync_every:
                 resync_fn = agg.resync

@@ -74,3 +74,30 @@ def device_mesh():
     mesh = staleness_mesh(model=2)
     assert mesh is not None and mesh.devices.size >= MULTIDEVICE_COUNT
     return mesh
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described TPU v5e:2x2 topology, as a sharding to lower
+    and compile for: the TPU compiler installed with JAX compiles for a chip
+    that is described, not attached, and nothing runs. Described here,
+    never at import, since only one process at a time may load the TPU
+    library. The persistent compilation cache is off around its tests: an
+    executable compiled for a described chip cannot be read back. Skips
+    only where no topology can be described."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()

@@ -7,7 +7,8 @@ Pins the tentpole contracts:
   * under injected NaN / explode / Byzantine / over-stale faults the host
     `StalenessSimulator` and the scanned engine replay each other ≤1e-5 for
     all five production algorithms, with identical guard counters, and every
-    run finishes with a finite model;
+    run finishes with a finite model, and the tree layout follows the flat
+    one through faults and an all-gone freeze;
   * periodic `Aggregator.resync` keeps the incremental ACED / CA²FL running
     sums matched to their O(n·d) direct references under faults, and heals
     injected state corruption between chunks;
@@ -161,6 +162,39 @@ def test_host_scan_parity_under_faults(algo):
     np.testing.assert_allclose(sr.losses, hr.losses, rtol=1e-4, atol=1e-5)
     assert sr.faults == hr.faults
     assert sum(sr.faults.values()) > 0, "schedule injected nothing"
+
+
+@pytest.mark.parametrize("cache_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("algo", ["ace", "aced", "ca2fl"])
+def test_tree_layout_matches_flat_under_faults_and_freeze(algo, cache_dtype):
+    """The per-arrival tick on the tree layout follows the flat one through
+    quarantined, refused and frozen arrivals: on both, the rule's masked
+    row write is the per-client cache's only gate (the flat run is held to
+    the host by the parity tests above)."""
+    grad_fn, params0 = _quad()
+
+    def tree_grad_fn(params, client, key):
+        loss, g = grad_fn(params["w"], client, key)
+        return loss, {"w": g}
+    rules = {"ace": lambda: ACEIncremental(cache_dtype=cache_dtype),
+             "aced": lambda: ACED(tau_algo=6, cache_dtype=cache_dtype),
+             "ca2fl": lambda: CA2FL(buffer_size=3, cache_dtype=cache_dtype)}
+    fa = _schedule(_n_events(rules[algo]))
+    # every client away over [8, 14): the run freezes, then thaws
+    windows = (np.full(N, 8, np.int64), np.full(N, 14, np.int64))
+    kw = dict(faults=fa, clip_norm=CLIP, windows=windows,
+              n_events=_n_events(rules[algo]))
+    flat = run_staleness_scan(**_scan_kw(algo, aggregator=rules[algo](),
+                                         **kw))
+    tree = run_staleness_scan(**_scan_kw(
+        algo, aggregator=rules[algo](), grad_fn=tree_grad_fn,
+        params0={"w": params0}, layout="tree", **kw))
+    w_tree = np.asarray(jax.tree.leaves(tree.w)[0]).reshape(flat.w.shape)
+    assert np.isfinite(flat.w).all()
+    assert np.max(np.abs(w_tree - flat.w)) <= 1e-5
+    assert tree.ts.tolist() == flat.ts.tolist()
+    assert tree.faults == flat.faults
+    assert sum(flat.faults.values()) > 0, "schedule injected nothing"
 
 
 def test_seed_sweep_surfaces_fault_counters():

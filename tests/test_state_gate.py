@@ -1,17 +1,20 @@
-"""The state gate of the K-batched scan tick.
+"""The state gate of the scan tick.
 
-On a K-batched tick (`step_k`) the per-client cache is gated by lane
-validity alone: `Aggregator.step_batch` writes it only through lane-masked
-row writes, so the tick's ``where(any(valid), new, old)`` keeps every other
-leaf of the aggregator state and passes the cache through
-(`scan_staleness._select_batch_state`). Pinned here:
+On both ticks the per-client cache is gated by validity alone: on a
+K-batched tick (`step_k`) `Aggregator.step_batch` writes it only through
+lane-masked row writes, and on a per-arrival tick (`step`) `Aggregator.step`
+writes it only through a row write masked by `Arrival.valid`. So the tick's
+``where(proc, new, old)`` keeps every other leaf of the aggregator state and
+passes the cache through (`scan_staleness._select_state`). Pinned here:
 
   * every cache rule with a `step_batch` (ACE, CA²FL, ACED), in both
     layouts, every cache dtype and with the fused commit on and off, leaves
-    each cache leaf bit-identical under a batch of invalid NaN lanes;
+    each cache leaf bit-identical under a batch of invalid NaN lanes; every
+    cache rule's `step` does the same under an invalid NaN arrival;
   * the compiled K-batched chunk has no select over the whole cache and,
     for ACE and CA²FL, no copy of it: the row scatter writes the loop carry
-    in place. The per-arrival tick (`step`) still copies the cache.
+    in place. The per-arrival chunk, compiled for a TPU v5e, has neither
+    either.
 """
 import re
 
@@ -21,8 +24,9 @@ import numpy as np
 import pytest
 
 from repro.configs.base import AFLConfig
-from repro.core.aggregators import (ACED, ACEIncremental, ArrivalBatch,
-                                    CA2FL, make_aggregator)
+from repro.core.aggregators import (ACED, ACEDDirect, ACEDirect,
+                                    ACEIncremental, Arrival, ArrivalBatch,
+                                    CA2FL, CA2FLDirect, make_aggregator)
 from repro.core.scan_staleness import (build_fault_schedule,
                                        build_staleness_randomness,
                                        make_chunked_staleness_runner)
@@ -39,6 +43,16 @@ RULES = {
     "aced": lambda dt, fused: ACED(tau_algo=3, cache_dtype=dt,
                                    max_cohort=K, fused_commit=fused),
 }
+#: every rule with a per-client cache, as its `step` runs at K = 1
+RULES_K1 = {
+    "ace": lambda dt: ACEIncremental(cache_dtype=dt),
+    "ca2fl": lambda dt: CA2FL(buffer_size=3, cache_dtype=dt),
+    "aced": lambda dt: ACED(tau_algo=3, cache_dtype=dt),
+    "ace_direct": lambda dt: ACEDirect(cache_dtype=dt),
+    "ca2fl_direct": lambda dt: CA2FLDirect(buffer_size=3, cache_dtype=dt),
+    "aced_direct": lambda dt: ACEDDirect(tau_algo=3, cache_dtype=dt),
+}
+DTYPES = ["int8", "bfloat16", "float32"]
 #: (layout, fused commit): the tree layout has only the dispatch chain
 LAYOUTS = [("flat", True), ("flat", False), ("tree", False)]
 #: an HLO instruction: its name, result shape without layout, and opcode
@@ -54,6 +68,17 @@ def _bits(tree):
     return [np.asarray(x).tobytes() for x in jax.tree.leaves(tree)]
 
 
+def _init_state(agg, layout, rng):
+    """The rule's state with its cache seeded from random rows."""
+    if layout == "flat":
+        init = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
+        return agg.init_state(N, D, init_grads=init)
+    init = jax.tree.map(
+        lambda p: jnp.asarray(rng.normal(size=(N,) + p.shape), jnp.float32),
+        PARAMS0)
+    return agg.init_state(N, PARAMS0, init_grads=init)
+
+
 def _lanes(layout, rng, scale=1.0):
     """K lanes of payload in the layout: (K, d) flat, PARAMS0-shaped tree
     leaves with a leading (K,) axis."""
@@ -64,7 +89,7 @@ def _lanes(layout, rng, scale=1.0):
                               jnp.float32), PARAMS0)
 
 
-@pytest.mark.parametrize("dt", ["int8", "bfloat16", "float32"])
+@pytest.mark.parametrize("dt", DTYPES)
 @pytest.mark.parametrize("layout,fused", LAYOUTS)
 @pytest.mark.parametrize("algo", sorted(RULES))
 def test_invalid_nan_lanes_leave_the_cache_bit_identical(algo, layout, fused,
@@ -75,14 +100,7 @@ def test_invalid_nan_lanes_leave_the_cache_bit_identical(algo, layout, fused,
     their bits."""
     rng = np.random.default_rng(0)
     agg = RULES[algo](dt, fused)
-    if layout == "flat":
-        init = jnp.asarray(rng.normal(size=(N, D)), jnp.float32)
-        state = agg.init_state(N, D, init_grads=init)
-    else:
-        init = jax.tree.map(
-            lambda p: jnp.asarray(rng.normal(size=(N,) + p.shape),
-                                  jnp.float32), PARAMS0)
-        state = agg.init_state(N, PARAMS0, init_grads=init)
+    state = _init_state(agg, layout, rng)
     step = jax.jit(agg.step_batch)
     state, *_ = step(state, ArrivalBatch(
         jnp.asarray([5, 0, 2, 7], jnp.int32), _lanes(layout, rng), 1,
@@ -107,6 +125,38 @@ def test_invalid_nan_lanes_leave_the_cache_bit_identical(algo, layout, fused,
         assert a[kept].tobytes() == b[kept].tobytes()
 
 
+@pytest.mark.parametrize("dt", DTYPES)
+@pytest.mark.parametrize("layout", ["flat", "tree"])
+@pytest.mark.parametrize("algo", sorted(RULES_K1))
+def test_invalid_nan_arrival_leaves_the_cache_bit_identical(algo, layout,
+                                                            dt):
+    """K = 1: an arrival the tick does not process (frozen, quarantined or
+    refused, so `Arrival.valid` is False), its payload NaN, leaves every
+    cache leaf bit for bit as it was through `Aggregator.step`; the same
+    arrival made valid rewrites its client's row and no other."""
+    rng = np.random.default_rng(0)
+    agg = RULES_K1[algo](dt)
+    state = _init_state(agg, layout, rng)
+    step = jax.jit(agg.step)
+    one = lambda lanes: jax.tree.map(lambda x: x[0], lanes)  # noqa: E731
+    state, *_ = step(state, Arrival(5, one(_lanes(layout, rng)), 1, 0))
+    before = _cache(state)
+    nan = jax.tree.map(lambda p: jnp.full_like(p, jnp.nan),
+                       one(_lanes(layout, rng)))
+    out, *_ = step(state, Arrival(3, nan, 2, 0, jnp.asarray(False)))
+    assert _bits(_cache(out)) == _bits(before)
+
+    out, *_ = step(state, Arrival(3, one(_lanes(layout, rng)), 2, 0,
+                                  jnp.asarray(True)))
+    rest = np.asarray([0, 1, 2, 4, 5, 6, 7])
+    moved = False
+    for a, b in zip(jax.tree.leaves(_cache(out)), jax.tree.leaves(before)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a[rest].tobytes() == b[rest].tobytes()
+        moved = moved or a[3].tobytes() != b[3].tobytes()
+    assert moved
+
+
 def _grad_fn(w, client, key):
     def loss(w):
         c = client.astype(jnp.float32)
@@ -114,9 +164,10 @@ def _grad_fn(w, client, key):
     return jax.value_and_grad(loss)(w)
 
 
-def _compiled_cache_ops(algo, layout, k, guarded):
-    """Compile the tiny chunk from shapes and return the opcodes of its
-    instructions whose result has an int8 cache leaf's shape."""
+def _compiled_cache_ops(algo, layout, k, guarded, sharding=None):
+    """Compile the tiny chunk from shapes, for the default device or for
+    `sharding`'s, and return the opcodes of its instructions whose result
+    has an int8 cache leaf's shape."""
     aflc = AFLConfig(algorithm=algo, n_clients=N, cache_dtype="int8",
                      k_batch=k)
     runner = make_chunked_staleness_runner(
@@ -133,6 +184,10 @@ def _compiled_cache_ops(algo, layout, k, guarded):
     if guarded:
         faults = build_fault_schedule(0, C, k_batch=k, nan_rate=0.25)
         args += [faults.kind, faults.scale, jnp.float32(1.0)]
+    if sharding is not None:
+        args = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype,
+                                           sharding=sharding), args)
     shapes = {"s8[%s]" % ",".join(map(str, x.shape))
               for x in jax.tree.leaves(_cache(carry["state"]))
               if x.dtype == jnp.int8}
@@ -170,11 +225,32 @@ def test_aced_k_batched_tick_drops_the_cache_select():
     assert [name for op, name in ops if op == "copy"]
 
 
+@pytest.mark.parametrize("guarded", [False, True])
 @pytest.mark.parametrize("layout", ["flat", "tree"])
-def test_per_arrival_tick_still_copies_the_cache(layout):
-    """`step` (K = 1) keeps its select over the cache and copies it: the
-    ACE transition reads the old row after the row write, so the write
-    cannot go to the loop carry in place."""
-    ops = _compiled_cache_ops("ace", layout, 1, False)
-    assert _selects(ops)
-    assert [name for op, name in ops if op == "copy"]
+@pytest.mark.parametrize("algo", ["ace", "ca2fl"])
+def test_per_arrival_tick_updates_the_cache_in_place(one_chip, algo, layout,
+                                                     guarded):
+    """The per-arrival chunk (K = 1), compiled for one chip of a described
+    TPU v5e, neither selects over the whole cache nor copies it: the rule's
+    masked row write takes the loop carry directly.
+
+    The CPU compiler still copies the cache here (two copies flat, four
+    tree): it fuses the old row's read into the consumers of the running
+    mean, which it schedules after the row write. So the chip's compiler is
+    the one this checks."""
+    ops = _compiled_cache_ops(algo, layout, 1, guarded, one_chip)
+    assert any(op in ("dynamic-update-slice", "fusion") for op, _ in ops)
+    assert _selects(ops) == []
+    assert [name for op, name in ops if op == "copy"] == []
+
+
+@pytest.mark.parametrize("layout", ["flat", "tree"])
+def test_aced_per_arrival_tick_updates_the_cache_in_place(one_chip, layout):
+    """ACED's `step` honours `Arrival.valid` as well, so its per-arrival
+    chunk passes the cache through the gate too. Compiled for a v5e it
+    keeps neither a whole-cache select nor a copy: the K = 1 expiry sweep
+    reads the cache before the row write. (Its K-batched chunk on the CPU
+    keeps copies; see above.)"""
+    ops = _compiled_cache_ops("aced", layout, 1, False, one_chip)
+    assert _selects(ops) == []
+    assert [name for op, name in ops if op == "copy"] == []

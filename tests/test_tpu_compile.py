@@ -7,20 +7,19 @@ the compiled program holds the Mosaic kernel (`tpu_custom_call`). Nothing
 runs. This catches what interpret mode cannot: operand shapes, tilings and
 VMEM use that the chip's compiler refuses.
 
-The topology is described inside a fixture, never at import: only one
-process at a time may load the TPU library, and test workers import every
-test file. The persistent compilation cache is off around these tests,
-because an executable compiled for a described chip cannot be read back.
+The topology is described inside the `one_chip` fixture (tests/conftest.py),
+never at import: only one process at a time may load the TPU library, and
+test workers import every test file. The persistent compilation cache is off
+around these tests, because an executable compiled for a described chip
+cannot be read back.
 """
 import importlib.util
-import os
 import re
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import cache_update, commit_batch, masked_agg, quant
 from repro.kernels import row_delta
@@ -28,24 +27,6 @@ from repro.kernels import row_delta
 D = 1 << 20
 K = 16
 N = 256
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler here: nothing to check
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", enabled)
-    compilation_cache.reset_cache()
 
 
 def _compile(fn, sharding, *shapes):

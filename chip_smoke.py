@@ -217,9 +217,12 @@ def flat_phase():
 
 def kernel_phase():
     """Every Pallas kernel against its XLA oracle at the flat widths, on
-    seeded random inputs with one quarantined (NaN, invalid) lane."""
+    seeded random inputs with one quarantined (NaN, invalid) lane; cache
+    rows in the cache's stored row shape, as the program passes them."""
+    from repro.core.cache import flat_row_shape
     from repro.kernels import ops, ref
     n, d, K = FLAT_N, FLAT_D, FLAT_K
+    row = flat_row_shape(d)
     keys = (jax.random.fold_in(jax.random.PRNGKey(7), i)
             for i in itertools.count())
     normal = lambda shape: jax.random.normal(next(keys), shape, jnp.float32)
@@ -236,7 +239,7 @@ def kernel_phase():
     for name, (R, la, lb, lg) in cases.items():
         for dtype in ("int8", "float32"):
             q = dtype == "int8"
-            rows = old_q if q else normal((K, d))
+            rows = (old_q if q else normal((K, d))).reshape((K,) + row)
             kw = dict(G=G, old_rows=rows, old_s=old_s if q else None,
                       new_s=new_s if q else None, valid=valid,
                       vecs=normal((R, d)), coef=normal((R, R + 4)),
@@ -259,7 +262,7 @@ def kernel_phase():
         # both return (f32 values, int8 row)
         _pair(name, fn(*a, backend="pallas")[::-1],
               fn(*a, backend="xla")[::-1], d)
-    cache = jax.random.randint(next(keys), (n, d), -127, 128, jnp.int8)
+    cache = jax.random.randint(next(keys), (n,) + row, -127, 128, jnp.int8)
     scales = jax.random.uniform(next(keys), (n,), jnp.float32, 1e-3, 1e-2)
     mask = jax.random.uniform(next(keys), (n,)) < 0.5
     err = rel_err(ops.masked_agg(cache, scales, mask, backend="pallas"),

@@ -604,15 +604,19 @@ class ACEIncremental(Aggregator):
         cache, u = state["cache"], state["u"]
         j = jnp.asarray(arr.client, jnp.int32)
         if isinstance(cache, FlatCache) and cache.data.dtype == jnp.int8:
+            # the stored row, (d // 128, 128) or (d,), as the kernel's (d,)
             c_row = jax.lax.dynamic_index_in_dim(cache.data, j, keepdims=False)
             old_scale = jax.lax.dynamic_index_in_dim(cache.scale, j,
                                                      keepdims=False)
             new_scale = kernel_ref.row_scale(arr.payload)
             u, q_row = kernel_ops.cache_row_update(
-                u, arr.payload, c_row, old_scale, new_scale, 1.0 / cache.n)
+                u, arr.payload, c_row.reshape(-1), old_scale, new_scale,
+                1.0 / cache.n)
             cache = FlatCache(
                 jax.lax.dynamic_update_index_in_dim(
-                    cache.data, jnp.where(arr.valid, q_row, c_row), j, 0),
+                    cache.data,
+                    jnp.where(arr.valid, q_row.reshape(c_row.shape), c_row),
+                    j, 0),
                 jax.lax.dynamic_update_index_in_dim(
                     cache.scale, jnp.where(arr.valid,
                                            new_scale.astype(jnp.float32),

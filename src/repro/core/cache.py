@@ -3,7 +3,9 @@ all-client aggregation possible (paper §3.4, Table a.3), with the paper's
 8-bit compression (App. F.3.3) as a first-class dtype.
 
 Two layouts:
-  * flat  — (n, d) array over raveled params (simulator / small models)
+  * flat  — n rows over raveled params (simulators, scan engines), each
+            stored in the row shape `flat_row_shape(d)`: whole (d // 128,
+            128) tiles behind the client index, so a row moves as one block
   * tree  — pytree of stacked leaves {q: (n, *s), scale: (n,)} (distributed)
 
 Quantization is symmetric per-row int8: scale = max|row| / 127. The ACE
@@ -17,11 +19,11 @@ The layout-generic ``cache_row`` / ``cache_set_row`` / ``cache_mean`` /
 engines on `FlatCache`, the pjit distributed path on tree caches — so the
 server rules exist exactly once.
 
-Sharding: flat-cache writes carry logical (cache_clients, cache_d) constraints
-(repro/sharding/rules.shard — a no-op outside a mesh context), so inside
-`use_rules(mesh)` the (n, d) cache lays out client-rows over the ``data`` axis
-and features over ``model`` (the sharded staleness scan,
-repro/core/scan_sharded.py).
+Sharding: flat-cache writes carry logical (cache_clients, cache_d, None)
+constraints (repro/sharding/rules.shard — a no-op outside a mesh context), so
+inside `use_rules(mesh)` the cache lays out client rows over the ``data``
+axis and each row's leading dimension over ``model`` (the sharded staleness
+scan, repro/core/scan_sharded.py).
 """
 from __future__ import annotations
 
@@ -38,7 +40,8 @@ INT8_MAX = 127.0
 
 
 def quantize_rows(x, axis=-1):
-    """x (..., d) -> (q int8, scale (...,)).
+    """x (..., d) -> (q int8, scale (...,)); `axis` may be a tuple, for a
+    row stored over several dimensions.
 
     Scale formula (clamp |max| before dividing) must match
     repro/kernels/ref.row_scale and the quant/tree-cache kernels — all int8
@@ -52,18 +55,72 @@ def dequantize_rows(q, scale, axis=-1):
     return q.astype(jnp.float32) * jnp.expand_dims(scale, axis)
 
 
+#: lanes of a TPU vector register: the minor dimension of a stored row
+LANES = 128
+
+
+def flat_row_shape(d: int) -> tuple:
+    """The shape one client's row of d values is stored in: whole
+    ``(d // 128, 128)`` tiles when 128 divides d, else ``(d,)``.
+
+    With the client index a major dimension over whole tiles, one row is one
+    contiguous block, so reading or writing K rows moves K blocks. A
+    row-tiled ``(n, d)`` int8 layout packs 32 clients into each tile: a row
+    write rewrites a band of 32 rows, and a gather of K rows reads every
+    client's column band."""
+    return (d // LANES, LANES) if d % LANES == 0 else (d,)
+
+
+def _shard_data(data):
+    """The cache's logical sharding: client rows over ``cache_clients``,
+    the row's leading dimension over ``cache_d``, its lanes unsharded."""
+    return shard(data, ("cache_clients", "cache_d")
+                 + (None,) * (data.ndim - 2))
+
+
+def _take_rows(data, idx):
+    """Rows ``data[idx[k]]`` (K, *row), one dynamic slice of a whole row
+    each: `jnp.take` over the clients lowers to a gather that reads every
+    client's column band of the cache."""
+    return jnp.stack([jax.lax.dynamic_index_in_dim(data, idx[k],
+                                                   keepdims=False)
+                      for k in range(idx.shape[0])])
+
+
+def _put_rows(data, idx, rows):
+    """``data`` with ``data[idx[k]] ← rows[k]`` (K, *row), one in-place
+    dynamic update of a whole row each, in lane order."""
+    for k in range(idx.shape[0]):
+        data = jax.lax.dynamic_update_index_in_dim(data, rows[k], idx[k], 0)
+    return data
+
+
 class FlatCache(NamedTuple):
-    """(n, d) gradient cache; data is int8 (with scale) or float."""
-    data: jax.Array              # (n, d) int8|bf16|f32
+    """Per-client cache over raveled params; data is int8 (with scale) or
+    float. Client i's row of d values is ``data[i]``, stored in the row
+    shape `flat_row_shape(d)` and read and written as (…, d) vectors."""
+    data: jax.Array              # (n, *flat_row_shape(d)) int8|bf16|f32
     scale: jax.Array             # (n,) f32 (unused for float dtypes)
 
     @property
     def n(self):
         return self.data.shape[0]
 
+    def _stored(self, rows):
+        """(…, d) rows in the stored row shape."""
+        return rows.reshape(rows.shape[:-1] + self.data.shape[1:])
+
+    def _dq(self):
+        """The whole cache dequantized to f32, in the stored shape."""
+        x = self.data.astype(jnp.float32)
+        if self.data.dtype == jnp.int8:
+            x = x * self.scale.reshape((-1,) + (1,) * (x.ndim - 1))
+        return x
+
     def row(self, i):
         i = jnp.asarray(i, jnp.int32)
-        r = jax.lax.dynamic_index_in_dim(self.data, i, keepdims=False)
+        r = jax.lax.dynamic_index_in_dim(self.data, i,
+                                         keepdims=False).reshape(-1)
         if self.data.dtype == jnp.int8:
             s = jax.lax.dynamic_index_in_dim(self.scale, i, keepdims=False)
             return r.astype(jnp.float32) * s
@@ -79,17 +136,17 @@ class FlatCache(NamedTuple):
             old_s = jax.lax.dynamic_index_in_dim(self.scale, i,
                                                  keepdims=False)
             return FlatCache(
-                shard(jax.lax.dynamic_update_index_in_dim(
-                    self.data, jnp.where(valid, q, old_raw), i, 0),
-                    ("cache_clients", "cache_d")),
+                _shard_data(jax.lax.dynamic_update_index_in_dim(
+                    self.data, jnp.where(valid, self._stored(q), old_raw),
+                    i, 0)),
                 shard(jax.lax.dynamic_update_index_in_dim(
                     self.scale, jnp.where(valid, s, old_s), i, 0),
                     ("cache_clients",)))
         return FlatCache(
-            shard(jax.lax.dynamic_update_index_in_dim(
+            _shard_data(jax.lax.dynamic_update_index_in_dim(
                 self.data,
-                jnp.where(valid, g.astype(self.data.dtype), old_raw), i, 0),
-                ("cache_clients", "cache_d")),
+                jnp.where(valid, self._stored(g.astype(self.data.dtype)),
+                          old_raw), i, 0)),
             self.scale)
 
     def set_row_delta(self, i, g, valid=True):
@@ -102,16 +159,17 @@ class FlatCache(NamedTuple):
         not `valid` the stored row and scale are written back bit for bit (a
         NaN `g` included) and `delta` is zero, as in `set_rows_delta`."""
         i = jnp.asarray(i, jnp.int32)
-        c_row = jax.lax.dynamic_index_in_dim(self.data, i, keepdims=False)
+        c_stored = jax.lax.dynamic_index_in_dim(self.data, i, keepdims=False)
+        c_row = c_stored.reshape(-1)
         if self.data.dtype == jnp.int8:
             old_scale = jax.lax.dynamic_index_in_dim(self.scale, i,
                                                      keepdims=False)
             new_scale = kernel_ref.row_scale(g)
             delta, q = kernel_ops.row_delta(g, c_row, old_scale, new_scale)
             cache = FlatCache(
-                shard(jax.lax.dynamic_update_index_in_dim(
-                    self.data, jnp.where(valid, q, c_row), i, 0),
-                    ("cache_clients", "cache_d")),
+                _shard_data(jax.lax.dynamic_update_index_in_dim(
+                    self.data, jnp.where(valid, self._stored(q), c_stored),
+                    i, 0)),
                 shard(jax.lax.dynamic_update_index_in_dim(
                     self.scale, jnp.where(valid, new_scale.astype(jnp.float32),
                                           old_scale), i, 0),
@@ -125,18 +183,19 @@ class FlatCache(NamedTuple):
         old = c_row.astype(jnp.float32)
         new_raw = g.astype(self.data.dtype)
         cache = FlatCache(
-            shard(jax.lax.dynamic_update_index_in_dim(
-                self.data, jnp.where(valid, new_raw, c_row), i, 0),
-                ("cache_clients", "cache_d")),
+            _shard_data(jax.lax.dynamic_update_index_in_dim(
+                self.data, jnp.where(valid, self._stored(new_raw), c_stored),
+                i, 0)),
             self.scale)
         delta = jnp.where(valid, new_raw.astype(jnp.float32) - old, 0.0)
         return cache, shard(delta, ("cache_d",)), shard(old, ("cache_d",))
 
     def rows(self, idx):
         """Dequantized f32 gather of rows ``idx`` (K,) — the batched read
-        behind the K-arrival engine (ACED cohort expiry, stale ring reads)."""
+        behind the K-arrival engine (ACED cohort expiry)."""
         idx = jnp.asarray(idx, jnp.int32)
-        r = jnp.take(self.data, idx, axis=0).astype(jnp.float32)
+        r = _take_rows(self.data, idx).reshape(idx.shape[0], -1
+                                               ).astype(jnp.float32)
         if self.data.dtype == jnp.int8:
             r = r * jnp.take(self.scale, idx, axis=0)[:, None]
         return shard(r, (None, "cache_d"))
@@ -155,47 +214,46 @@ class FlatCache(NamedTuple):
         if valid is None:
             valid = jnp.ones((K,), jnp.bool_)
         vcol = valid[:, None]
+        old_raw = _take_rows(self.data, idx).reshape(K, -1)
         if self.data.dtype == jnp.int8:
-            old_q = jnp.take(self.data, idx, axis=0)
             old_s = jnp.take(self.scale, idx, axis=0)
-            old = old_q.astype(jnp.float32) * old_s[:, None]
+            old = old_raw.astype(jnp.float32) * old_s[:, None]
             new_s = jnp.maximum(jnp.max(jnp.abs(G), axis=-1), 1e-12) / INT8_MAX
             new_q = jnp.clip(jnp.round(G / new_s[:, None]), -127, 127
                              ).astype(jnp.int8)
             dq_new = new_q.astype(jnp.float32) * new_s[:, None]
             delta = jnp.where(vcol, dq_new - old, 0.0)
             cache = FlatCache(
-                shard(self.data.at[idx].set(jnp.where(vcol, new_q, old_q)),
-                      ("cache_clients", "cache_d")),
+                _shard_data(_put_rows(self.data, idx, self._stored(
+                    jnp.where(vcol, new_q, old_raw)))),
                 shard(self.scale.at[idx].set(
                     jnp.where(valid, new_s.astype(jnp.float32), old_s)),
                     ("cache_clients",)))
             return (cache, shard(delta, (None, "cache_d")),
                     shard(old, (None, "cache_d")))
-        old_raw = jnp.take(self.data, idx, axis=0)
         old = old_raw.astype(jnp.float32)
         new_raw = G.astype(self.data.dtype)
         delta = jnp.where(vcol, new_raw.astype(jnp.float32) - old, 0.0)
         cache = FlatCache(
-            shard(self.data.at[idx].set(jnp.where(vcol, new_raw, old_raw)),
-                  ("cache_clients", "cache_d")),
+            _shard_data(_put_rows(self.data, idx, self._stored(
+                jnp.where(vcol, new_raw, old_raw)))),
             self.scale)
         return (cache, shard(delta, (None, "cache_d")),
                 shard(old, (None, "cache_d")))
 
     def dequant(self):
         """(n, d) f32 view."""
-        if self.data.dtype == jnp.int8:
-            return self.data.astype(jnp.float32) * self.scale[:, None]
-        return self.data.astype(jnp.float32)
+        return self._dq().reshape(self.n, -1)
 
     def mean(self, mask=None):
-        """Direct aggregation (paper Alg. 1 line 10 / Alg. a.1 line 7)."""
-        rows = self.dequant()
+        """Direct aggregation (paper Alg. 1 line 10 / Alg. a.1 line 7),
+        reduced over the clients in the stored shape."""
+        rows = self._dq()
         if mask is None:
-            return jnp.mean(rows, axis=0)
+            return jnp.mean(rows, axis=0).reshape(-1)
         m = mask.astype(jnp.float32)
-        return jnp.sum(rows * m[:, None], 0) / jnp.maximum(jnp.sum(m), 1.0)
+        s = jnp.sum(rows * m.reshape((-1,) + (1,) * (rows.ndim - 1)), 0)
+        return s.reshape(-1) / jnp.maximum(jnp.sum(m), 1.0)
 
     def nbytes(self) -> int:
         return self.data.size * self.data.dtype.itemsize + self.scale.nbytes
@@ -203,28 +261,36 @@ class FlatCache(NamedTuple):
 
 def init_flat_cache(n: int, d: int, dtype: str = "float32",
                     init_rows=None) -> FlatCache:
+    """An (n, d) cache stored as (n, *flat_row_shape(d)); `init_rows`,
+    (n, d) or already (n, *flat_row_shape(d)), seeds the rows (int8:
+    quantized per row)."""
     dt = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "int8": jnp.int8}[dtype]
+    stored = (n,) + flat_row_shape(d)
     if init_rows is not None:
         if dt == jnp.int8:
-            q, s = quantize_rows(init_rows)
-            return FlatCache(shard(q, ("cache_clients", "cache_d")),
-                             shard(s, ("cache_clients",)))
-        return FlatCache(shard(init_rows.astype(dt),
-                               ("cache_clients", "cache_d")),
+            # quantize in the stored shape: rows that come stacked in it
+            # are read once and the int8 cache is written once
+            q, s = quantize_rows(init_rows.reshape(stored),
+                                 axis=tuple(range(1, len(stored))))
+            return FlatCache(_shard_data(q), shard(s, ("cache_clients",)))
+        return FlatCache(_shard_data(init_rows.astype(dt).reshape(stored)),
                          jnp.ones((n,), jnp.float32))
-    return FlatCache(shard(jnp.zeros((n, d), dt),
-                           ("cache_clients", "cache_d")),
+    return FlatCache(_shard_data(jnp.zeros(stored, dt)),
                      jnp.ones((n,), jnp.float32))
 
 
 def flat_commit_batch(cache: FlatCache, idx, G, valid, vecs, coef, upd_w,
                       lane_a=None, lane_b=None, lane_g=None):
-    """The whole K-arrival commit as ONE fused pass (ISSUE 10): gather the
-    K old rows, requantize+scatter the new ones, fold the masked segment
+    """The whole K-arrival commit as ONE fused pass: read the
+    K old rows, requantize+write the new ones, fold the masked segment
     sums into the stacked running-sum vectors ``vecs (R, d)`` via the
     ``coef (R, R+4)`` recombination and emit the ``upd_w``-weighted model
     update — `kernels/ops.commit_batch` behind the backend-aware dispatch
     (Pallas megakernel on TPU, exact XLA oracle elsewhere).
+
+    The K rows move as whole rows in the stored row shape, which the
+    kernel takes as it is: K dynamic slices on the client dimension in, K
+    dynamic updates out, in place on a donated cache.
 
     Returns ``(cache', vecs' (R, d) f32, update (d,) f32)``. The written
     rows are bit-identical to `FlatCache.set_rows_delta` (valid lanes
@@ -236,7 +302,7 @@ def flat_commit_batch(cache: FlatCache, idx, G, valid, vecs, coef, upd_w,
     sharded scan consumes this path unchanged."""
     idx = jnp.asarray(idx, jnp.int32)
     G = G.astype(jnp.float32)
-    old_rows = jnp.take(cache.data, idx, axis=0)
+    old_rows = _take_rows(cache.data, idx)
     if cache.data.dtype == jnp.int8:
         old_s = jnp.take(cache.scale, idx, axis=0)
         # scale the *sanitized* payloads: an invalid lane's NaN must not
@@ -248,8 +314,7 @@ def flat_commit_batch(cache: FlatCache, idx, G, valid, vecs, coef, upd_w,
             G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
             lane_a=lane_a, lane_b=lane_b, lane_g=lane_g)
         new_cache = FlatCache(
-            shard(cache.data.at[idx].set(new_rows),
-                  ("cache_clients", "cache_d")),
+            _shard_data(_put_rows(cache.data, idx, new_rows)),
             shard(cache.scale.at[idx].set(
                 jnp.where(valid, new_s.astype(jnp.float32), old_s)),
                 ("cache_clients",)))
@@ -258,8 +323,7 @@ def flat_commit_batch(cache: FlatCache, idx, G, valid, vecs, coef, upd_w,
             G, old_rows, None, None, valid, vecs, coef, upd_w,
             lane_a=lane_a, lane_b=lane_b, lane_g=lane_g)
         new_cache = FlatCache(
-            shard(cache.data.at[idx].set(new_rows),
-                  ("cache_clients", "cache_d")),
+            _shard_data(_put_rows(cache.data, idx, new_rows)),
             cache.scale)
     return (new_cache, shard(vecs_out, (None, "cache_d")),
             shard(update, ("cache_d",)))
@@ -533,10 +597,11 @@ def cache_sum(cache, mask=None):
     rules' running sums (ACED's asum/init_sum) and the periodic
     `Aggregator.resync` exact recompute; never on a per-event hot path."""
     if isinstance(cache, FlatCache):
-        rows = cache.dequant()
+        rows = cache._dq()
         if mask is None:
-            return rows.sum(0)
-        return jnp.sum(rows * mask.astype(jnp.float32)[:, None], 0)
+            return rows.sum(0).reshape(-1)
+        m = mask.astype(jnp.float32).reshape((-1,) + (1,) * (rows.ndim - 1))
+        return jnp.sum(rows * m, 0).reshape(-1)
 
     def leaf(c):
         rows = c["q"].astype(jnp.float32)

@@ -98,7 +98,7 @@ from jax.flatten_util import ravel_pytree
 from repro.core import sanitize
 from repro.core.aggregators import (Aggregator, Arrival, ArrivalBatch,
                                     wants_cache_init)
-from repro.core.cache import (FlatCache, init_tree_cache,
+from repro.core.cache import (FlatCache, flat_row_shape, init_tree_cache,
                               is_tree_cache_leaf, tree_cache_row,
                               tree_cache_rows, tree_cache_set_row)
 from repro.core.scan_engine import (ScanResult, _payload_chain, _to_result,
@@ -547,7 +547,11 @@ def _staleness_program(*, grad_fn: Callable, params0,
             jnp.zeros((marks.shape[0], d_tpl), jnp.float32),
             (None, "cache_d"))
         snap_update = snapshot_update
-        init_mean = lambda rows: jnp.mean(rows, 0)
+        # the init scan stacks each client's row in the cache's stored row
+        # shape: laying out the (n, d) f32 stack again to seed the cache
+        # would take a second copy of it (8 GiB at the flat cell's size)
+        init_row = lambda p: p.reshape(flat_row_shape(d_tpl))
+        init_mean = lambda rows: jnp.mean(rows, 0).reshape(-1)
         apply_init = lambda w, eta, mean: w - eta * mean
         apply_update = lambda w, u, eta, emit: shard(
             jnp.where(emit, w - eta * u, w), ("cache_d",))
@@ -594,6 +598,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
                                        x[None], s), snaps, w)
             return snaps, jnp.logical_or(hits, hit)
 
+        init_row = lambda p: p
         init_mean = lambda rows: jax.tree.map(lambda r: jnp.mean(r, 0), rows)
         apply_init = lambda w, eta, mean: jax.tree.map(
             lambda wl, m: wl - eta * m.astype(jnp.float32), w, mean)
@@ -610,7 +615,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
         if wants_init:
             def init_step(key, client):
                 p, _, key = payload_fn(w0, client, key)
-                return key, pin_payload(p)
+                return key, init_row(pin_payload(p))
             key, init_rows = jax.lax.scan(init_step, key, jnp.arange(n, dtype=jnp.int32))
             state = agg.init_state(n, d_tpl, init_rows)
             # paper Alg. 1 line 4-5: apply u^0 before the loop

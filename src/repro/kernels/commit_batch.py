@@ -14,7 +14,9 @@ round-tripping HBM between ops. Exactness contract: a valid lane's delta
 subtracts exactly the previously-added dequantized row, and an invalid
 lane's stored row/scale stays bit-exact (`cache_set_rows_delta` semantics).
 
-Operand layout per tile: payloads/old rows (K, block_d), state vectors
+Operand layout per tile: payloads (K, block_d), old rows in the cache's
+stored row shape (`cache.flat_row_shape`: (K, block_d // 128, 128) or
+(K, block_d)), reshaped to (K, block_d) in VMEM, state vectors
 (R, block_d), the per-lane scalars packed as one (6, K) f32 block
 [old_s, new_s, valid, w_a, w_b, w_g] and the affine recombination as one
 (R+1, R+4) f32 block [coef; upd_w]. Statically absent lane weights skip
@@ -46,17 +48,18 @@ def _kernel(lanes_ref, mats_ref, g_ref, c_ref, v_ref,
     # and the lane weights are 0 there by construction, so zeroing Ĝ makes
     # every downstream product finite
     Gs = jnp.where(vcol, G, 0.0)
-    c = c_ref[...]
+    c = c_ref[...].reshape(G.shape)
     if quantized:
         old = c.astype(jnp.float32) * old_s
         q = jnp.clip(jnp.round(Gs / new_s), -127.0, 127.0)
-        rows_ref[...] = jnp.where(vcol, q.astype(jnp.int8), c)
+        new_rows = jnp.where(vcol, q.astype(jnp.int8), c)
         dq_new = q * new_s
     else:
         old = c.astype(jnp.float32)
         stored = Gs.astype(c.dtype)
-        rows_ref[...] = jnp.where(vcol, stored, c)
+        new_rows = jnp.where(vcol, stored, c)
         dq_new = stored.astype(jnp.float32)
+    rows_ref[...] = new_rows.reshape(rows_ref.shape)
     s_old = _matvec(lanes[2:3], old)
     sd = _matvec(lanes[2:3], dq_new) - s_old
     z = jnp.zeros_like(sd)
@@ -82,7 +85,11 @@ def commit_batch(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
                  lane_a=None, lane_b=None, lane_g=None, *,
                  block_d: int = BLOCK_D, interpret: bool | None = None):
     """Fused batched commit; same signature/semantics as `ref.commit_batch_ref`
-    -> ``(new_rows (K, d), vecs' (R, d) f32, update (d,) f32)``.
+    -> ``(new_rows (K, *row), vecs' (R, d) f32, update (d,) f32)``.
+
+    `old_rows` (K, *row) and `new_rows` keep the cache's stored row shape,
+    (d // 128, 128) or (d,), so they move to and from the cache as whole
+    rows; each tile is reshaped to (K, block_d) in VMEM.
 
     `old_s`/`new_s` are (K,) f32 for an int8 cache, None for float caches;
     `lane_a`/`lane_b`/`lane_g` are optional (K,) f32 lane weights (zero on
@@ -106,13 +113,19 @@ def commit_batch(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
     mats = jnp.concatenate([coef, upd_w[None]], axis=0).astype(jnp.float32)
     G = G.astype(jnp.float32)
     V = vecs.astype(jnp.float32)
+    # the row's leading dimension tiles d: `unit` values per index
+    row = old_rows.shape[1:]
+    unit = d // row[0]
     pad = (-d) % block_d
     if pad:
         G = jnp.pad(G, ((0, 0), (0, pad)))
-        old_rows = jnp.pad(old_rows, ((0, 0), (0, pad)))
+        old_rows = jnp.pad(old_rows, ((0, 0), (0, pad // unit))
+                           + ((0, 0),) * (len(row) - 1))
         V = jnp.pad(V, ((0, 0), (0, pad)))
     dp = d + pad
-    row_spec = pl.BlockSpec((K, block_d), lambda i: (0, i))
+    g_spec = pl.BlockSpec((K, block_d), lambda i: (0, i))
+    row_spec = pl.BlockSpec((K, block_d // unit) + row[1:],
+                            lambda i: (0, i) + (0,) * (len(row) - 1))
     vec_spec = pl.BlockSpec((R, block_d), lambda i: (0, i))
     kern = functools.partial(
         _kernel, quantized=quantized, has_a=lane_a is not None,
@@ -122,10 +135,10 @@ def commit_batch(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
         grid=(dp // block_d,),
         in_specs=[pl.BlockSpec((6, K), lambda i: (0, 0)),
                   pl.BlockSpec((R + 1, R + 4), lambda i: (0, 0)),
-                  row_spec, row_spec, vec_spec],
+                  g_spec, row_spec, vec_spec],
         out_specs=[row_spec, vec_spec,
                    pl.BlockSpec((block_d,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((K, dp), old_rows.dtype),
+        out_shape=[jax.ShapeDtypeStruct(old_rows.shape, old_rows.dtype),
                    jax.ShapeDtypeStruct((R, dp), jnp.float32),
                    jax.ShapeDtypeStruct((dp,), jnp.float32)],
         interpret=interpret,
@@ -133,4 +146,4 @@ def commit_batch(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
         # after whatever jit or scope wraps this call
         name="commit_batch",
     )(lanes, mats, G, old_rows, V)
-    return rows[:, :d], vecs_out[:, :d], upd[:d]
+    return rows[:, :row[0]], vecs_out[:, :d], upd[:d]

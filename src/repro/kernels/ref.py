@@ -55,10 +55,11 @@ def row_delta_ref(g, c_row, old_scale, new_scale):
 
 
 def masked_agg_ref(cache, scales, mask):
-    """cache (n,d) int8; scales (n,) f32; mask (n,) bool -> (d,) f32."""
+    """cache (n, *row) int8; scales (n,) f32; mask (n,) bool -> (d,) f32."""
     m = mask.astype(jnp.float32)
     w = m * scales
-    acc = jnp.einsum("nd,n->d", cache.astype(jnp.float32), w)
+    # contract the clients in the stored shape, then flatten the (…) result
+    acc = jnp.tensordot(w, cache.astype(jnp.float32), axes=1).reshape(-1)
     return acc / jnp.maximum(jnp.sum(m), 1.0)
 
 
@@ -79,8 +80,10 @@ def commit_batch_ref(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
 
     Inputs
       G        (K, d) f32   arriving payloads (invalid lanes may be NaN)
-      old_rows (K, d)       gathered cache rows: int8 (with `old_s`/`new_s`
-                            (K,) f32 scales) or a float dtype (scales None)
+      old_rows (K, *row)    the cache's K rows in its stored row shape
+                            (`cache.flat_row_shape`): int8 (with `old_s`/
+                            `new_s` (K,) f32 scales) or a float dtype
+                            (scales None)
       valid    (K,) bool    guard mask — invalid lanes are perfect no-ops
       vecs     (R, d) f32   stacked running-sum state vectors, R ∈ {1, 2, 3}
       coef     (R, R+4) f32 affine recombination, one row per output vector
@@ -95,7 +98,7 @@ def commit_batch_ref(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
       S_A = Σ_k lane_a_k·dq(old_k),  S_B analogous
       S_G = Σ_k lane_g_k·Ĝ_k        (Ĝ = payloads zeroed on invalid lanes)
 
-    Returns ``(new_rows (K, d), vecs' (R, d) f32, update (d,) f32)``.
+    Returns ``(new_rows (K, *row), vecs' (R, d) f32, update (d,) f32)``.
     `new_rows` is bit-identical to `FlatCache.set_rows_delta`'s write: valid
     lanes quantize with `new_s`, invalid lanes keep the stored row bit-exact.
     The sums are lane-weighted broadcast-multiply-reduces (NOT dot_general):
@@ -107,6 +110,8 @@ def commit_batch_ref(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
     vf = valid.astype(jnp.float32)
     vcol = valid[:, None]
     G = G.astype(jnp.float32)
+    row = old_rows.shape[1:]
+    old_rows = old_rows.reshape(G.shape)
     # single sanitization point: quarantined lanes may carry NaN/inf, and
     # every downstream product must see a finite 0 there instead
     Gs = jnp.where(vcol, G, 0.0)
@@ -140,4 +145,4 @@ def commit_batch_ref(G, old_rows, old_s, new_s, valid, vecs, coef, upd_w,
     basis = jnp.concatenate(parts, 0)
     mats = jnp.concatenate([coef, upd_w[None]], 0)[:, jnp.asarray(cols)]
     out = jnp.sum(mats[:, :, None] * basis[None, :, :], axis=1)
-    return new_rows, out[:-1], out[-1]
+    return new_rows.reshape(new_rows.shape[:1] + row), out[:-1], out[-1]

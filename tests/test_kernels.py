@@ -41,6 +41,25 @@ def test_masked_agg_matches_ref(n, d, blk):
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("n,d,blk", [(4, 512, 128), (16, 3072, 1024)])
+def test_masked_agg_takes_stored_rows(n, d, blk):
+    """The masked mean over a cache stored as (n, d // 128, 128) is the
+    mean over the same cache laid out (n, d)."""
+    rng = np.random.default_rng(4)
+    q, s = ref.quantize_rows_ref(
+        jnp.asarray(rng.normal(size=(n, d)), jnp.float32))
+    mask = jnp.asarray(rng.random(n) >= 0.5)
+    tiles = q.reshape(n, d // 128, 128)
+    u1 = masked_agg(tiles, s, mask, interpret=True, block_d=blk)
+    u2 = masked_agg(q, s, mask, interpret=True, block_d=blk)
+    assert u1.shape == (d,)
+    np.testing.assert_allclose(np.asarray(u1), np.asarray(u2),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(ref.masked_agg_ref(tiles, s, mask)),
+                               np.asarray(ref.masked_agg_ref(q, s, mask)),
+                               rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("d,blk", [(512, 128), (4096, 2048), (1000, 512),
                                    (129, 128)])
 def test_cache_row_update_matches_ref(d, blk):
@@ -117,6 +136,30 @@ def test_commit_batch_matches_ref(K, d, blk, quantized, R, lanes):
     rows1, vecs1, upd1 = commit_batch(**kw, block_d=blk, interpret=True)
     rows2, vecs2, upd2 = ref.commit_batch_ref(**kw)
     assert jnp.array_equal(rows1, rows2)           # cache rows bit-exact
+    np.testing.assert_allclose(np.asarray(vecs1), np.asarray(vecs2),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(upd1), np.asarray(upd2),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("K,d,blk,quantized,R,lanes", [
+    (16, 4096, 2048, True, 1, ()),                 # ACE at the tile block
+    (4, 1280, 512, True, 2, ("a", "b")),           # non-dividing: padded
+    (3, 512, 256, False, 3, ("a", "g")),           # float cache
+])
+def test_commit_batch_takes_stored_rows(K, d, blk, quantized, R, lanes):
+    """Rows in the cache's stored (d // 128, 128) shape go in and come out
+    in it: the same bits as the (K, d) rows, and the oracle's sums."""
+    kw = commit_inputs(5 * K + d, K, d, R, quantized, lanes)
+    flat_rows = kw["old_rows"]
+    kw["old_rows"] = flat_rows.reshape(K, d // 128, 128)
+    rows1, vecs1, upd1 = commit_batch(**kw, block_d=blk, interpret=True)
+    rows2, vecs2, upd2 = ref.commit_batch_ref(**kw)
+    rows3, _, _ = commit_batch(**{**kw, "old_rows": flat_rows}, block_d=blk,
+                               interpret=True)
+    assert rows1.shape == (K, d // 128, 128)
+    assert jnp.array_equal(rows1, rows2)
+    assert jnp.array_equal(rows1.reshape(K, d), rows3)
     np.testing.assert_allclose(np.asarray(vecs1), np.asarray(vecs2),
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(upd1), np.asarray(upd2),

@@ -154,6 +154,20 @@ def test_sharded_scan_int8_cache(algo, factory, device_mesh):
     _assert_matches(sr, shr, host=hr)
 
 
+@pytest.mark.parametrize("algo,factory", [
+    ("ace", lambda: ACEIncremental(cache_dtype="int8")),
+    ("aced", lambda: ACED(tau_algo=5, cache_dtype="int8")),
+    ("aced_direct", lambda: ACEDDirect(tau_algo=5, cache_dtype="int8")),
+    ("ca2fl", lambda: CA2FL(buffer_size=4, cache_dtype="int8")),
+])
+def test_sharded_scan_tiled_int8_cache(algo, factory, device_mesh):
+    """d = 256 stores each client's row as (2, 128) tiles: the cache's
+    constraint (clients → data, the row's tiles → model, lanes unsharded)
+    keeps the trajectories of the unsharded scan and the host replay."""
+    sim, hr, sr, shr = _three_way(factory, device_mesh, d=256, T=30)
+    _assert_matches(sr, shr, host=hr)
+
+
 @pytest.mark.parametrize("inc,dr", [
     (lambda dt: ACED(tau_algo=5, cache_dtype=dt),
      lambda dt: ACEDDirect(tau_algo=5, cache_dtype=dt)),
@@ -258,6 +272,25 @@ def test_cache_rows_actually_sharded(device_mesh):
     expect = (n // dd if n % dd == 0 else n, d // dm if d % dm == 0 else d)
     assert sharding.shard_shape(state["cache"].data.shape) == expect
     assert expect != (n, d)           # something actually sharded
+
+
+def test_tiled_cache_rows_actually_sharded(device_mesh):
+    """A cache whose rows are whole (d // 128, 128) tiles shards its
+    clients over ``data`` and its tile rows over ``model``."""
+    n, d, T = 8, 512, 10
+    runner = make_sharded_staleness_runner(
+        mesh=device_mesh, grad_fn=quad_grad_fn(n, d), params0=jnp.zeros(d),
+        aggregator=ACEIncremental(cache_dtype="int8"), n_clients=n, T=T,
+        beta=2.0)
+    rand = build_staleness_randomness(
+        0, default_n_events(ACEIncremental(), T), n, 2.0)
+    w, state, _, _ = runner(jax.random.PRNGKey(0), rand.gumbels, rand.tau_raw,
+                            rand.leave_at, rand.rejoin_at, jnp.float32(0.05))
+    data = state["cache"].data
+    dd, dm = device_mesh.shape["data"], device_mesh.shape["model"]
+    assert data.shape == (n, d // 128, 128)
+    assert data.sharding.shard_shape(data.shape) == (n // dd,
+                                                     d // 128 // dm, 128)
 
 
 def test_staleness_mesh_helper(device_mesh):

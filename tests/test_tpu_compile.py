@@ -14,6 +14,7 @@ around these tests, because an executable compiled for a described chip
 cannot be read back.
 """
 import importlib.util
+import math
 import re
 from pathlib import Path
 
@@ -21,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import cache_update, commit_batch, masked_agg, quant
+from repro.core.cache import FlatCache, flat_commit_batch, flat_row_shape
+from repro.kernels import cache_update, commit_batch, masked_agg, ops, quant
 from repro.kernels import row_delta
 
 D = 1 << 20
@@ -150,3 +152,74 @@ def test_kernel_op_name_is_pinned(one_chip, kernel):
         reader = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(reader)
         assert all(reader.NAME.match(op) for op in ops)
+
+
+#: the flat cell's cache: 512 clients of d = 2^22 int8 values (2 GiB).
+#: At this width XLA writes the K rows as one in-place update each; its
+#: cost model counts the stack of K row reads at six times their bytes, so
+#: at a narrower d the payload's f32 reads would fill the budget below
+N_CACHE, D_FLAT = 512, 1 << 22
+#: an HLO instruction: its name, result shape without layout, and opcode
+INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (\S+?)(?:\{[^}]*\})? "
+                   r"([\w\-]+)\(")
+
+
+def _cache_copies(text, n_elems):
+    """Names of the `copy` instructions whose int8 result holds as many
+    elements as the whole cache, in whatever shape."""
+    out = []
+    for m in map(INSTR.match, text.splitlines()):
+        if not m or m.group(3) != "copy" or not m.group(2).startswith("s8["):
+            continue
+        dims = m.group(2)[3:-1].split(",")
+        if dims != [""] and math.prod(map(int, dims)) == n_elems:
+            out.append(m.group(1))
+    return out
+
+
+def test_flat_commit_moves_only_the_committed_rows(one_chip, monkeypatch):
+    """ACE's fused K = 16 commit on the flat cell's int8 cache, the carry
+    donated, compiled for one v5e chip: the K old rows are read as whole
+    rows, with no XLA gather over every client's column band
+    (`mini-gather`), the cache is never copied whole, and the program
+    accesses under half of the cache's bytes (about a quarter). Reading
+    the rows with `jnp.take` from an (n, d) cache accesses 2.7 times
+    them."""
+    # steer the kernel dispatch to the compiled kernel: the code sees a CPU
+    monkeypatch.setattr(ops, "default_backend", lambda: "pallas")
+    monkeypatch.setattr(commit_batch, "default_interpret", lambda: False)
+    coef = jnp.asarray([[1.0, 1.0 / N_CACHE, 0.0, 0.0, 0.0]], jnp.float32)
+
+    def fn(data, scale, idx, G, valid, u):
+        cache, _, update = flat_commit_batch(
+            FlatCache(data, scale), idx, G, valid, u[None], coef, coef[0])
+        return cache, update
+
+    f32 = jnp.float32
+    shapes = (((N_CACHE,) + flat_row_shape(D_FLAT), jnp.int8),
+              ((N_CACHE,), f32), ((K,), jnp.int32), ((K, D_FLAT), f32),
+              ((K,), jnp.bool_), ((D_FLAT,), f32))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "mini-gather" not in text
+    assert _cache_copies(text, N_CACHE * D_FLAT) == []
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 0.5 * N_CACHE * D_FLAT
+
+
+def test_masked_agg_reads_the_stored_cache_in_place(one_chip):
+    """The masked mean over the whole int8 cache (ACED's direct reference)
+    takes the cache in its stored row shape, so the compiled kernel reads
+    it where it lies: no copy of the whole cache lays it out (n, d)."""
+    shape = (N_CACHE,) + flat_row_shape(D_FLAT)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in ((shape, jnp.int8), ((N_CACHE,), jnp.float32),
+                          ((N_CACHE,), jnp.bool_))]
+    text = jax.jit(lambda c, s, m: masked_agg.masked_agg(
+        c, s, m, interpret=False)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _cache_copies(text, N_CACHE * D_FLAT) == []

@@ -263,9 +263,10 @@ class Aggregator:
         aggregation and one emission decision for the whole batch. Same
         trace-safety contract as `step`; invalid lanes must be perfect
         no-ops. `step` with a singleton batch is the K=1 sanity anchor; the
-        engines run K=1 ticks on `step`.
+        scan tick commits through `step` at K = 1 and through `step_batch`
+        above it.
 
-        The K-batched scan tick relies on this for the per-client cache:
+        The scan tick at K > 1 relies on this for the per-client cache:
         lane validity is its only gate, as `arr.valid` is `step`'s. The
         cache must be written only through lane-masked row writes that put
         each invalid lane's stored row and scale back bit for bit, NaN

@@ -104,17 +104,3 @@ def make_sharded_staleness_runner(*, mesh, **kwargs):
     run.base = base
     return run
 
-
-def make_sharded_chunked_staleness_runner(*, mesh, **kwargs):
-    """Chunked flavour (`ChunkedStalenessRunner`) under ``use_rules(mesh)``
-    — the checkpointable executor `launch/train.py` drives when more than
-    one device is visible. Thin alias: `make_chunked_staleness_runner`
-    already wraps every init/chunk call in the mesh context when one is
-    given; this entry point exists for symmetry and the explicit
-    mesh-required contract."""
-    if mesh is None:
-        raise ValueError("make_sharded_chunked_staleness_runner needs a "
-                         "mesh; use make_chunked_staleness_runner for "
-                         "single-device runs")
-    from repro.core.scan_staleness import make_chunked_staleness_runner
-    return make_chunked_staleness_runner(mesh=mesh, **kwargs)

@@ -109,8 +109,8 @@ from repro.core.staleness_sim import (FAULT_BYZANTINE, FAULT_EXPLODE,
                                       staleness_client_probs)
 from repro.sharding.rules import replicate, shard, use_rules
 
-#: The stages of one scan tick, in code order. Each wraps its part of `step`
-#: and `step_k` in a `jax.named_scope`, so the compiled chunk's op metadata —
+#: The stages of one scan tick, in code order. Each wraps its part of the
+#: tick in a `jax.named_scope`, so the compiled chunk's op metadata —
 #: and a profile of it — names the stage every op came from (see
 #: `ChunkedStalenessRunner.op_stages`). "afl.guards" is built only with
 #: guards and "afl.resync" only with `resync_every`. Outputs, the next t and
@@ -367,7 +367,7 @@ def _select_state(pred, new, old):
     the host loop (which performs no transitions while frozen), and
     through quarantined or refused arrivals.
 
-    Validity is the only gate on the cache, on both ticks: `Aggregator.step`
+    Validity is the only gate on the cache at every K: `Aggregator.step`
     gets `pred` as `Arrival.valid`, `step_batch` the lanes' validity, and
     each writes the cache only through masked row writes that put the
     stored row and scale back bit for bit, so on a tick that `pred` refuses
@@ -382,21 +382,21 @@ def _select_state(pred, new, old):
         new, old, is_leaf=_is_cache)
 
 
-def _tree_global_norm(tree):
-    """‖tree‖₂ over all leaves — the tree layout's `unorm` metric, equal to
-    ``jnp.linalg.norm`` of the raveled vector."""
-    return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
+def _tree_norm(tree, lane_dims: int = 0):
+    """‖·‖₂ over all leaves of `tree`, taken over each leaf's axes past its
+    leading `lane_dims` lane axes: with none, the global norm (the tree
+    layout's `unorm` metric, equal to ``jnp.linalg.norm`` of the raveled
+    vector); with a (K,) lane axis, lane k's global norm at k."""
+    return jnp.sqrt(sum(jnp.sum(jnp.square(x.astype(jnp.float32)),
+                                axis=tuple(range(lane_dims, x.ndim)))
                         for x in jax.tree.leaves(tree)))
 
 
-def _tree_lane_norms(tree):
-    """(K,) per-lane ‖·‖₂ over a pytree whose leaves carry a leading (K,)
-    lane axis — the K-batch guard pipeline's per-lane global norm (lane k's
-    value equals `_tree_global_norm` of lane k's slice)."""
-    sq = sum(jnp.sum(jnp.square(x.astype(jnp.float32)),
-                     axis=tuple(range(1, x.ndim)))
-             for x in jax.tree.leaves(tree))
-    return jnp.sqrt(sq)
+def _per_lane(x, leaf):
+    """Lane values `x`, of the tick's lane shape (``()`` or ``(K,)``), shaped
+    to broadcast against `leaf`, which leads with the same lane axes (a
+    scalar broadcasts as it is)."""
+    return x.reshape(x.shape + (1,) * (leaf.ndim - x.ndim)) if x.ndim else x
 
 
 def _tree_payload_chain(grad_fn, local_steps: int, local_lr: float):
@@ -496,18 +496,12 @@ def _staleness_program(*, grad_fn: Callable, params0,
     if not 1 <= k_batch <= n_clients:
         raise ValueError(
             f"k_batch={k_batch} must be in [1, n_clients={n_clients}]")
-    if k_batch > 1:
-        # ``k_batch=1`` runs the original per-event step verbatim
-        # (bit-identity contract); K>1 consumes K arrivals per scan tick:
-        # Gumbel top-k sampling, one `ArrivalBatch` into `step_batch`, one
-        # ring append and one model update per tick. ``tau_raw`` (and the
-        # fault arrays under guards) must carry a (K,) lane axis.
-        mc = getattr(agg, "max_cohort", None)
-        if mc is not None and mc < k_batch:
-            raise ValueError(
-                f"{type(agg).__name__}(max_cohort={mc}) cannot own "
-                f"k_batch={k_batch} cohorts — construct the aggregator "
-                "with max_cohort >= k_batch")
+    mc = getattr(agg, "max_cohort", None)
+    if mc is not None and mc < k_batch:
+        raise ValueError(
+            f"{type(agg).__name__}(max_cohort={mc}) cannot own "
+            f"k_batch={k_batch} cohorts — construct the aggregator "
+            "with max_cohort >= k_batch")
     tau_max = tau_max if tau_max is not None else default_tau_max(beta)
     S = tau_max + 1
     wants_init = init_cache_grads and wants_cache_init(agg)
@@ -605,9 +599,78 @@ def _staleness_program(*, grad_fn: Callable, params0,
         apply_update = lambda w, u, eta, emit: jax.tree.map(
             lambda wl, ul: jnp.where(emit, wl - eta * ul.astype(jnp.float32),
                                      wl), w, u)
-        unorm = _tree_global_norm
+        unorm = _tree_norm
     else:
         raise ValueError(f"unknown layout {layout!r}")
+
+    # What a tick's arrival count decides, picked here once; the tick itself
+    # is written once over a lane shape of () (K = 1) or (K,). K = 1 samples
+    # the Gumbel argmax, reads one ring slot, runs one payload on the carry's
+    # key chain and commits one `Arrival`; K > 1 samples the Gumbel top-k
+    # (the K distinct clients in sampling order; ties break to the lower
+    # index, as the host reference's stable argsort does), reads K slots,
+    # runs K payload lanes and commits one `ArrivalBatch`. ``tau_raw`` (and
+    # the fault arrays under guards) then carry the (K,) lane axis.
+    if k_batch == 1:
+        lanes = ()
+
+        def sample(scores, gone, any_alive):
+            # the argmax client is alive whenever any client is
+            return jnp.argmax(scores).astype(jnp.int32), any_alive
+
+        read, client = rd_ring, payload_fn
+        next_key = lambda key: key
+
+        def commit(state, j, p, t, tau, valid):
+            return agg.step(state, Arrival(j, p, t, tau, valid)), valid
+
+        tick_loss = lambda losses, valid: losses
+        # the guard flags of a tick are booleans
+        flag = lambda win, alive, m: jnp.logical_and(win, m)
+        lane_checks = lambda *args: None
+    else:
+        lanes = (k_batch,)
+
+        def sample(scores, gone, any_alive):
+            # gone clients sink to -inf; with fewer than K alive the lanes
+            # past them are masked off
+            _, js = jax.lax.top_k(scores, k_batch)
+            js = js.astype(jnp.int32)
+            return js, jnp.logical_not(gone[js])
+
+        read = rd_rings
+
+        def client(w_stales, js, key):
+            # keys[1 + i] seeds lane i's payload and keys[0] advances the
+            # carry's chain (the host reference splits identically)
+            keys = jax.random.split(key, k_batch + 1)
+            payloads, losses, _ = jax.vmap(payload_fn)(w_stales, js,
+                                                       keys[1:])
+            return payloads, losses, keys
+
+        # taken where the next carry is built, outside every stage
+        next_key = lambda keys: keys[0]
+
+        def commit(state, js, p, t, taus, valid):
+            # no valid lane (the all-gone freeze included): no transition
+            proc = jnp.any(valid)
+            return agg.step_batch(
+                state, ArrivalBatch(js, p, t, taus, valid)), proc
+
+        def tick_loss(losses, valid):
+            # the mean over the valid lanes
+            nv = jnp.sum(valid.astype(jnp.float32))
+            return (jnp.sum(jnp.where(valid, losses, 0.0))
+                    / jnp.maximum(nv, 1.0))
+
+        def flag(win, alive, m):
+            # the guard flags of a tick count its live lanes
+            c = jnp.sum(jnp.logical_and(alive, m).astype(jnp.int32))
+            return jnp.where(win, c, 0)
+
+        def lane_checks(js, taus, valid, u, state, old):
+            sanitize.check_batch_arrivals(js, taus, valid, n, tau_max)
+            sanitize.check_commit_batch(u, state, old, valid)
 
     def init_fn(key, lr, w0):
         lr = jnp.asarray(lr, jnp.float32)
@@ -650,7 +713,7 @@ def _staleness_program(*, grad_fn: Callable, params0,
         leave_at = jnp.asarray(leave_at, jnp.int32)
         rejoin_at = jnp.asarray(rejoin_at, jnp.int32)
 
-        def step(carry, ev):
+        def tick(carry, ev):
             if guards:
                 g_row, traw, f_kind, f_scale = ev
             else:
@@ -670,55 +733,59 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 any_alive = jnp.any(~gone)
                 thaw_t = jnp.minimum(
                     jnp.min(jnp.where(gone, rejoin_at, NEVER)), T)
-                j = jnp.argmax(logits + g_row).astype(jnp.int32)
+                js, alive = sample(logits + g_row, gone, any_alive)
                 tau_req = jnp.floor(traw).astype(jnp.int32)
                 if guards:   # injected over-stale request; clamped for read
                     tau_req = jnp.where(f_kind == FAULT_OVERSTALE,
                                         tau_max + 1, tau_req)
-                tau = jnp.minimum(tau_req,
-                                  jnp.minimum(tau_max, carry["n_upd"]))
+                taus = jnp.minimum(tau_req,
+                                   jnp.minimum(tau_max, carry["n_upd"]))
             with _stage("afl.stale_read"):
-                w_stale = rd_ring(carry["ring"], carry["cursor"], tau)
+                w_stale = read(carry["ring"], carry["cursor"], taus)
             with _stage("afl.client"):
-                payload, loss, key = payload_fn(w_stale, j, carry["key"])
+                payload, losses, key = client(w_stale, js, carry["key"])
                 payload = pin_payload(payload)
             if guards:
                 with _stage("afl.guards"):
-                    # fault injection: one scalar multiplier covers NAN
+                    # fault injection: one multiplier per lane covers NAN
                     # (payload goes non-finite), EXPLODE (norm blow-up by
-                    # f_scale) and BYZANTINE (sign flip); clean events
+                    # f_scale) and BYZANTINE (sign flip); clean lanes
                     # multiply by 1.0 — an f32 identity, so a no-fault
-                    # guarded run tracks the unguarded trajectory exactly
+                    # guarded run tracks the unguarded trajectory exactly,
+                    # and a faulty lane never vetoes its batch
                     mult = jnp.where(f_kind == FAULT_NAN, jnp.float32(jnp.nan),
                                      jnp.float32(1.0))
                     mult = mult * jnp.where(f_kind == FAULT_EXPLODE, f_scale,
                                             jnp.float32(1.0))
                     mult = jnp.where(f_kind == FAULT_BYZANTINE, -mult, mult)
-                    payload = jax.tree.map(lambda p: p * mult, payload)
-                    finite = jnp.asarray(True)
+                    payload = jax.tree.map(lambda p: p * _per_lane(mult, p),
+                                           payload)
+                    finite = jnp.ones(lanes, jnp.bool_)
                     for leaf in jax.tree.leaves(payload):
-                        finite = jnp.logical_and(finite,
-                                                 jnp.all(jnp.isfinite(leaf)))
-                    gnorm = _tree_global_norm(payload)
+                        finite = jnp.logical_and(finite, jnp.all(
+                            jnp.isfinite(leaf),
+                            axis=tuple(range(len(lanes), leaf.ndim))))
+                    gnorm = _tree_norm(payload, len(lanes))
                     # NaN gnorm compares False: a quarantined payload is never
                     # also counted as clipped
                     do_clip = jnp.logical_and(clip_norm > 0, gnorm > clip_norm)
                     cscale = jnp.where(
                         do_clip, clip_norm / jnp.maximum(gnorm, 1e-12),
                         jnp.float32(1.0))
-                    payload = jax.tree.map(lambda p: p * cscale, payload)
+                    payload = jax.tree.map(lambda p: p * _per_lane(cscale, p),
+                                           payload)
                     reject = tau_req > tau_max
                     ok = jnp.logical_and(finite, jnp.logical_not(reject))
-                    proc = jnp.logical_and(any_alive, ok)
+                    valid = jnp.logical_and(alive, ok)
             else:
-                proc = any_alive
+                valid = alive
             with _stage("afl.commit"):
-                state, u, emit, lr_scale = agg.step(
-                    carry["state"], Arrival(j, payload, t, tau, proc))
+                (state, u, emit, lr_scale), proc = commit(
+                    carry["state"], js, payload, t, taus, valid)
                 emit = jnp.logical_and(emit, jnp.logical_and(t < T, proc))
             # frozen events perform no aggregator transition on the host —
-            # and neither do quarantined/rejected ones: the masked row write
-            # keeps the cache, the select the running sums and the ACED
+            # and neither do quarantined/rejected ones: the masked row writes
+            # keep the cache, the select the running sums and the ACED
             # owner-ring untouched (jnp.where also stops any NaN from
             # leaking out of the unselected branch)
             with _stage("afl.select"):
@@ -745,28 +812,25 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 ring, cursor = ap_ring(carry["ring"], carry["cursor"], w,
                                        emit)
             t_new = jnp.where(any_alive, t + emit.astype(jnp.int32), thaw_t)
-            out = {"loss": loss, "emit": emit, "t": t,
+            out = {"loss": tick_loss(losses, valid), "emit": emit, "t": t,
                    "unorm": unorm(u), "alive": any_alive}
             if record_w:
                 out["w"] = w
-            new_carry = {"w": w, "key": key, "state": state, "t": t_new,
-                         "n_upd": n_upd_new,
+            new_carry = {"w": w, "key": next_key(key), "state": state,
+                         "t": t_new, "n_upd": n_upd_new,
                          "ring": ring, "cursor": cursor}
             if marks is not None:
                 new_carry["snaps"], new_carry["hits"] = snap_update(
                     carry["snaps"], carry["hits"], marks, t_new, emit, w)
             if guards:
-                # counters gated on the live window (t < T, not frozen) so
-                # the padding tail/freezes never count and chunked totals
-                # equal the host loop's
+                # flags gated on the live window (t < T, not frozen) and the
+                # live lanes, so the padding tail and freezes never count and
+                # chunked totals equal the host loop's
                 win = jnp.logical_and(t < T, any_alive)
                 flags = {
-                    "quarantined": jnp.logical_and(win,
-                                                   jnp.logical_not(finite)),
-                    "rejected": jnp.logical_and(
-                        win, jnp.logical_and(finite, reject)),
-                    "clipped": jnp.logical_and(
-                        win, jnp.logical_and(ok, do_clip))}
+                    "quarantined": flag(win, alive, jnp.logical_not(finite)),
+                    "rejected": flag(win, alive, jnp.logical_and(finite, reject)),
+                    "clipped": flag(win, alive, jnp.logical_and(ok, do_clip))}
                 out.update(flags)
                 new_carry["guards"] = {
                     k: carry["guards"][k] + flags[k].astype(jnp.int32)
@@ -775,163 +839,19 @@ def _staleness_program(*, grad_fn: Callable, params0,
                 # debug-build value invariants (repro/core/sanitize.py);
                 # the static flag means an off build traces ZERO extra ops
                 sanitize.check_model_finite(w)
-                sanitize.check_payload_finite(payload, applied=emit)
-                sanitize.check_cursor_bounds(cursor, S)
-                sanitize.check_aggregator_state(state, n)
-            return new_carry, out
-
-        def step_k(carry, ev):
-            # K-arrival tick: same protocol skeleton as `step`, but the
-            # tick's K sampled clients flow through per-lane guards into ONE
-            # `step_batch` transition — one ring append, one model update.
-            if guards:
-                g_row, traw_k, f_kind, f_scale = ev
-            else:
-                g_row, traw_k = ev
-            t = carry["t"]
-            with _stage("afl.sample"):
-                g_row = shard(g_row, ("cache_clients",))
-                gone = jnp.logical_and(leave_at <= t, t < rejoin_at)
-                logits = jnp.where(gone, -jnp.inf, log_probs)
-                any_alive = jnp.any(~gone)
-                thaw_t = jnp.minimum(
-                    jnp.min(jnp.where(gone, rejoin_at, NEVER)), T)
-                # Gumbel top-k: the K distinct clients of this tick, in
-                # sampling order (ties break to the lower index — the host
-                # reference mirrors with a stable argsort of the negated
-                # scores). Gone clients sink to -inf; with fewer than K alive
-                # their lanes are masked off below.
-                _, js = jax.lax.top_k(logits + g_row, k_batch)
-                js = js.astype(jnp.int32)
-                lane_alive = jnp.logical_not(gone[js])
-                tau_req = jnp.floor(traw_k).astype(jnp.int32)      # (K,)
-                if guards:
-                    tau_req = jnp.where(f_kind == FAULT_OVERSTALE, tau_max + 1,
-                                        tau_req)
-                taus = jnp.minimum(tau_req,
-                                   jnp.minimum(tau_max, carry["n_upd"]))
-            with _stage("afl.stale_read"):
-                w_stales = rd_rings(carry["ring"], carry["cursor"], taus)
-            with _stage("afl.client"):
-                # per-lane PRNG: keys[0] advances the carry chain, keys[1+i]
-                # seeds lane i's payload (the host reference splits
-                # identically; payload_fn's own internal splits stay per-lane
-                # deterministic)
-                keys = jax.random.split(carry["key"], k_batch + 1)
-                payloads, losses, _ = jax.vmap(payload_fn)(w_stales, js,
-                                                           keys[1:])
-                payloads = pin_payload(payloads)
-            if guards:
-                with _stage("afl.guards"):
-                    # the same multiplier chain as `step`, vectorized per
-                    # lane — a faulty lane is quarantined/rejected
-                    # individually and never vetoes its batch
-                    mult = jnp.where(f_kind == FAULT_NAN, jnp.float32(jnp.nan),
-                                     jnp.float32(1.0))
-                    mult = mult * jnp.where(f_kind == FAULT_EXPLODE, f_scale,
-                                            jnp.float32(1.0))
-                    mult = jnp.where(f_kind == FAULT_BYZANTINE, -mult, mult)
-                    payloads = jax.tree.map(
-                        lambda p: p * mult.reshape(
-                            (-1,) + (1,) * (p.ndim - 1)), payloads)
-                    finite = jnp.ones((k_batch,), jnp.bool_)
-                    for leaf in jax.tree.leaves(payloads):
-                        finite = jnp.logical_and(
-                            finite, jnp.all(jnp.isfinite(leaf),
-                                            axis=tuple(range(1, leaf.ndim))))
-                    gnorms = _tree_lane_norms(payloads)
-                    do_clip = jnp.logical_and(clip_norm > 0,
-                                              gnorms > clip_norm)
-                    cscale = jnp.where(
-                        do_clip, clip_norm / jnp.maximum(gnorms, 1e-12),
-                        jnp.float32(1.0))
-                    payloads = jax.tree.map(
-                        lambda p: p * cscale.reshape(
-                            (-1,) + (1,) * (p.ndim - 1)), payloads)
-                    reject = tau_req > tau_max
-                    ok = jnp.logical_and(finite, jnp.logical_not(reject))
-                    valid = jnp.logical_and(lane_alive, ok)
-            else:
-                valid = lane_alive
-            with _stage("afl.commit"):
-                # `proc` covers the all-gone freeze too: every lane dead ⇒ no
-                # transition, model/state held, t fast-forwards to the thaw
-                # (the cache by its invalid lanes, the rest by the select)
-                proc = jnp.any(valid)
-                state, u, agg_emit, lr_scale = agg.step_batch(
-                    carry["state"], ArrivalBatch(js, payloads, t, taus, valid))
-                emit = jnp.logical_and(agg_emit,
-                                       jnp.logical_and(t < T, proc))
-            with _stage("afl.select"):
-                state = _select_state(proc, state, carry["state"])
-            n_upd_new = carry["n_upd"] + emit.astype(jnp.int32)
-            if resync_every:
-                resync_fn = agg.resync
-                if checkify_invariants:
-                    def resync_fn(s):
-                        s2 = agg.resync(s)
-                        sanitize.check_resync_agreement(s, s2)
-                        return s2
-                with _stage("afl.resync"):
-                    state = jax.lax.cond(
-                        jnp.logical_and(
-                            emit, jnp.mod(n_upd_new, resync_every) == 0),
-                        resync_fn, lambda s: s, state)
-            with _stage("afl.update"):
-                eta = lr_of_t(t, lr) * lr_scale
-                w = apply_update(carry["w"], u, eta, emit)
-            with _stage("afl.ring"):
-                ring, cursor = ap_ring(carry["ring"], carry["cursor"], w,
-                                       emit)
-            t_new = jnp.where(any_alive, t + emit.astype(jnp.int32), thaw_t)
-            nv = jnp.sum(valid.astype(jnp.float32))
-            loss = (jnp.sum(jnp.where(valid, losses, 0.0))
-                    / jnp.maximum(nv, 1.0))
-            out = {"loss": loss, "emit": emit, "t": t,
-                   "unorm": unorm(u), "alive": any_alive}
-            if record_w:
-                out["w"] = w
-            new_carry = {"w": w, "key": keys[0], "state": state, "t": t_new,
-                         "n_upd": n_upd_new,
-                         "ring": ring, "cursor": cursor}
-            if marks is not None:
-                new_carry["snaps"], new_carry["hits"] = snap_update(
-                    carry["snaps"], carry["hits"], marks, t_new, emit, w)
-            if guards:
-                # per-tick COUNTS (int32, vs the K=1 booleans): only live
-                # lanes in the live window count, so chunked totals equal
-                # the host loop's per-lane bookkeeping
-                win = jnp.logical_and(t < T, any_alive)
-
-                def cnt(m):
-                    c = jnp.sum(jnp.logical_and(lane_alive, m)
-                                .astype(jnp.int32))
-                    return jnp.where(win, c, 0)
-
-                flags = {"quarantined": cnt(jnp.logical_not(finite)),
-                         "rejected": cnt(jnp.logical_and(finite, reject)),
-                         "clipped": cnt(jnp.logical_and(ok, do_clip))}
-                out.update(flags)
-                new_carry["guards"] = {
-                    k: carry["guards"][k] + flags[k] for k in flags}
-            if checkify_invariants:
-                sanitize.check_model_finite(w)
                 # quarantined lanes legitimately carry NaN — check only the
-                # lanes the batch actually applied
-                applied_lanes = jax.tree.map(
-                    lambda p: jnp.where(
-                        valid.reshape((-1,) + (1,) * (p.ndim - 1)), p, 0.0),
-                    payloads)
-                sanitize.check_payload_finite(applied_lanes, applied=emit)
+                # lanes the tick applied
+                applied = jax.tree.map(
+                    lambda p: jnp.where(_per_lane(valid, p), p, 0.0), payload)
+                sanitize.check_payload_finite(applied, applied=emit)
                 sanitize.check_cursor_bounds(cursor, S)
                 sanitize.check_aggregator_state(state, n)
-                sanitize.check_batch_arrivals(js, taus, valid, n, tau_max)
-                sanitize.check_commit_batch(u, state, carry["state"], valid)
+                lane_checks(js, taus, valid, u, state, carry["state"])
             return new_carry, out
 
         xs = ((gumbels, tau_raw, fault_kind, fault_scale) if guards
               else (gumbels, tau_raw))
-        return jax.lax.scan(step if k_batch == 1 else step_k, carry, xs)
+        return jax.lax.scan(tick, carry, xs)
 
     if guards:
         def chunk_fn(carry, gumbels, tau_raw, leave_at, rejoin_at, lr,
@@ -998,7 +918,7 @@ def make_staleness_runner(*, grad_fn: Callable, params0,
     aggregation, one ring append + model update), so ``tau_raw`` — and the
     fault arrays under guards — must carry a trailing (k_batch,) lane axis
     (`build_staleness_randomness(..., k_batch=...)`). ``k_batch=1``
-    compiles the original per-event program bit-identically."""
+    commits one `Arrival` a tick through `step`."""
     do_checkify = sanitize.enabled(checkify_invariants)
     init_fn, chunk_fn, marks, w0 = _staleness_program(
         grad_fn=grad_fn, params0=params0, aggregator=aggregator,
@@ -1053,7 +973,7 @@ class ChunkedStalenessRunner:
     #: True when the debug value sanitizers are compiled into `chunk`
     #: (repro/core/sanitize) — chunk then raises on a violated invariant
     checkify_invariants: bool = False
-    #: arrivals consumed per scan tick (1 = the original per-event engine);
+    #: arrivals consumed per scan tick (1: one `Arrival` through `step`);
     #: the chunked event slices must carry the matching tau_raw/fault lane
     #: axis — see `_staleness_program`
     k_batch: int = 1

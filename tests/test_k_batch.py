@@ -1,8 +1,8 @@
 """Event-batched server steps (ISSUE 9): K arrivals consumed per scan tick.
 
 Pins the tentpole contracts:
-  * ``k_batch=1`` is BIT-identical to the unbatched engine — the batched
-    body is a gated dispatch, not a rewrite of the K=1 hot path;
+  * the scan's trajectories at K = 1 and K = 4 are bit for bit those
+    recorded in `tests/data/k_batch_golden.npz` (see `GOLDEN`);
   * K>1 device scans replay the host K-batch `StalenessSimulator` reference
     ≤1e-5 for all five production algorithms (Gumbel top-k sampling, per-lane
     payload keys, one aggregated server update per tick), on the flat
@@ -19,11 +19,13 @@ Pins the tentpole contracts:
     and a mis-shaped fault schedule are rejected up front.
 """
 import functools
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.flatten_util import ravel_pytree
 
 from repro.core.aggregators import (ACED, ACEIncremental, Arrival,
                                     ArrivalBatch, CA2FL, FedBuff,
@@ -90,19 +92,70 @@ def _pair(algo, k, n=N, t=T, faults=None, clip_norm=0.0, resync_every=None,
 ALGOS = ["asgd", "fedbuff", "ca2fl", "ace", "aced"]
 
 
-@pytest.mark.parametrize("algo", ALGOS)
-def test_k1_is_bit_identical_to_unbatched_engine(algo):
-    """The k_batch=1 build must reproduce the pre-batching engine bit for
-    bit — same scan body, same randomness stream, zero deviation."""
+#: Trajectories recorded once from the source of commit 833ab96, whose scan
+#: still ran K = 1 and K > 1 on two separate tick bodies: running this module
+#: as a script (``PYTHONPATH=<that commit's src> python tests/test_k_batch.py``)
+#: writes every case of `GOLDEN_CASES` through `_golden_run` into this file.
+GOLDEN = Path(__file__).parent / "data" / "k_batch_golden.npz"
+#: the five rules on the flat layout at K = 1 (named by the rule alone) and
+#: K = 4, ACE on the int8 tree layout (int8 cache and history ring), and a
+#: guarded, faulted ACED build with a resync cadence, each at both K
+GOLDEN_CASES = (ALGOS + [f"{a}-k{K}" for a in ALGOS]
+                + ["ace-tree-int8", f"ace-tree-int8-k{K}",
+                   "aced-guarded", f"aced-guarded-k{K}"])
+GOLDEN_KEYS = ("w", "losses", "ts", "update_norms", "emit", "faults")
+
+
+def _golden_run(case):
+    """The scan of one golden case: {key: array} over `GOLDEN_KEYS`, the
+    guard counters as (quarantined, clipped, rejected)."""
+    name, lanes, _ = case.partition(f"-k{K}")
+    k = K if lanes else 1
+    algo = name.split("-")[0]
     grad_fn, params0 = _quad()
-    kw = dict(grad_fn=grad_fn, params0=params0, aggregator=_agg(algo),
-              n_clients=N, server_lr=LR, T=T, beta=BETA, seed=SEED)
-    base = run_staleness_scan(**kw)
-    k1 = run_staleness_scan(k_batch=1, **kw)
-    np.testing.assert_array_equal(np.asarray(k1.w), np.asarray(base.w))
-    np.testing.assert_array_equal(np.asarray(k1.losses),
-                                  np.asarray(base.losses))
-    assert k1.ts.tolist() == base.ts.tolist()
+    kw = dict(grad_fn=grad_fn, params0=params0, n_clients=N, server_lr=LR,
+              T=T, beta=BETA, seed=SEED, k_batch=k)
+    if name.endswith("tree-int8"):
+        agg = ACEIncremental(cache_dtype="int8")
+        tree0 = {"a": jnp.zeros((D // 2,), jnp.float32),
+                 "b": jnp.zeros((2, D // 4), jnp.float32)}
+        unravel = ravel_pytree(tree0)[1]
+
+        def tree_grad(params, client, key):
+            loss, g = grad_fn(ravel_pytree(params)[0], client, key)
+            return loss, unravel(g)
+        kw.update(grad_fn=tree_grad, params0=tree0, layout="tree",
+                  history_dtype="int8")
+    elif name.endswith("guarded"):
+        agg = _agg(algo, k)
+        n_events = default_n_events(agg, T) + 40
+        kw.update(faults=build_fault_schedule(
+            7, n_events, k_batch=k, nan_rate=0.1, explode_rate=0.08,
+            byzantine_rate=0.08, overstale_rate=0.08),
+            clip_norm=5.0, resync_every=8)
+    else:
+        agg = _agg(algo, k)
+    r = run_staleness_scan(aggregator=agg, **kw)
+    return {"w": np.asarray(r.w), "losses": np.asarray(r.losses),
+            "ts": np.asarray(r.ts), "update_norms": np.asarray(r.update_norms),
+            "emit": np.asarray(r.emit),
+            "faults": np.asarray([r.faults.get(f, -1) for f in
+                                  ("quarantined", "clipped", "rejected")])}
+
+
+@pytest.mark.parametrize("algo", GOLDEN_CASES)
+def test_k1_is_bit_identical_to_unbatched_engine(algo):
+    """At K = 1 and K = 4 the one tick body reproduces, bit for bit, the
+    trajectories recorded from the two separate tick bodies it replaced:
+    model, losses, emit cadence, update norms and guard counters, on both
+    layouts, with faults, guards and resync (`GOLDEN`)."""
+    got = _golden_run(algo)
+    with np.load(GOLDEN) as golden:
+        for key in GOLDEN_KEYS:
+            np.testing.assert_array_equal(got[key], golden[f"{algo}/{key}"],
+                                          err_msg=f"{algo}: {key}")
+    if algo.startswith("aced-guarded"):
+        assert got["faults"].sum() > 0, "schedule injected nothing"
 
 
 @pytest.mark.parametrize("algo", ALGOS)
@@ -357,3 +410,10 @@ def test_host_k_batch_requires_replay_and_matching_faults():
                              clip_norm=5.0, **kw)
     with pytest.raises(ValueError, match="fault schedule"):
         sim.run(T)
+
+
+if __name__ == "__main__":
+    GOLDEN.parent.mkdir(exist_ok=True)
+    np.savez(GOLDEN, **{f"{case}/{key}": value
+                        for case in GOLDEN_CASES
+                        for key, value in _golden_run(case).items()})

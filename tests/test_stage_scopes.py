@@ -1,6 +1,6 @@
 """Stage scopes of the scan tick and the trainer's host spans.
 
-Every stage of `step` / `step_k` sits in a `jax.named_scope` named in
+Every stage of the scan tick sits in a `jax.named_scope` named in
 `scan_staleness.STAGES`; `ChunkedStalenessRunner.op_stages` maps each
 instruction of the compiled chunk to its stage. The scopes are metadata
 only: the compiled program is the same instruction for instruction with

@@ -1,9 +1,9 @@
 """The state gate of the scan tick.
 
-On both ticks the per-client cache is gated by validity alone: on a
-K-batched tick (`step_k`) `Aggregator.step_batch` writes it only through
-lane-masked row writes, and on a per-arrival tick (`step`) `Aggregator.step`
-writes it only through a row write masked by `Arrival.valid`. So the tick's
+At every K the per-client cache is gated by validity alone: on a
+K-batched tick `Aggregator.step_batch` writes it only through lane-masked
+row writes, and on a per-arrival tick (K = 1) `Aggregator.step` writes it
+only through a row write masked by `Arrival.valid`. So the tick's
 ``where(proc, new, old)`` keeps every other leaf of the aggregator state and
 passes the cache through (`scan_staleness._select_state`). Pinned here:
 

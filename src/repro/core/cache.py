@@ -6,6 +6,7 @@ Two layouts:
   * flat  — n rows over raveled params (simulators, scan engines), each
             stored in the row shape `flat_row_shape(d)`: whole (d // 128,
             128) tiles behind the client index, so a row moves as one block
+            (the scan's f32 model-history ring stores its slots the same way)
   * tree  — pytree of stacked leaves {q: (n, *s), scale: (n,)} (distributed)
 
 Quantization is symmetric per-row int8: scale = max|row| / 127. The ACE

@@ -190,8 +190,8 @@ def history_ring_bytes(params, tau_max: int,
 
     layout="tree": `init_tree_cache(tau_max+1, params, history_dtype)` — a
     per-leaf stacked (S, *shape) buffer; the int8 layout adds one (S,) f32
-    scale per leaf. layout="flat": a raw (S, d) f32 ring (the flat engines
-    never quantize their history)."""
+    scale per leaf. layout="flat": a raw (S, *flat_row_shape(d)) f32 ring,
+    S * d values (the flat engines never quantize their history)."""
     S = tau_max + 1
     d = sum(int(x.size) for x in jax.tree.leaves(params))
     if layout == "flat":

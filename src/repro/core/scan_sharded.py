@@ -3,18 +3,19 @@
 
 ACE/ACED pay for their participation-imbalance robustness with an O(n·d)
 per-client cache (paper Table a.3), and the scanned engine adds a
-``(tau_max+1, d)`` ring-buffer model history plus an ``(n_marks, d)`` eval
-snapshot buffer. On one chip those buffers bound the reachable
-(n_clients × model-size) corner of the Fig. 2/3 sweeps; this module shards
-them so the same scan spans a pod:
+``(tau_max+1, *flat_row_shape(d))`` ring-buffer model history plus an
+``(n_marks, d)`` eval snapshot buffer. On one chip those buffers bound the
+reachable (n_clients × model-size) corner of the Fig. 2/3 sweeps; this
+module shards them so the same scan spans a pod:
 
   * **aggregator cache** ``(n_clients, d)`` — client rows over ``data``,
     features over ``model`` (logical axes ``cache_clients``/``cache_d``,
     repro/sharding/rules.py) — the exact layout the pjit train step in
     repro/core/distributed.py uses, so the scan and the pod-scale path fuse;
-  * **ring buffer** ``(tau_max+1, d)`` and **snapshot buffer**
-    ``(n_marks, d)`` — history/mark slots replicated, features over
-    ``model``;
+  * **ring buffer** ``(tau_max+1, *flat_row_shape(d))`` (each slot in the
+    cache's row shape) and **snapshot buffer** ``(n_marks, d)`` —
+    history/mark slots replicated, features (a slot's leading dimension)
+    over ``model``, lanes unsharded;
   * **gumbel rows** ``(n_clients,)`` — over ``data`` (client sampling).
 
 Mechanically this is the GSPMD flavour of pjit: `make_staleness_runner`

@@ -20,9 +20,10 @@ This engine scans the full protocol on device:
      and per-client **availability windows** ``leave_at``/``rejoin_at``
      (drawn once; permanent dropout = ``rejoin_at = NEVER``, always-on =
      ``leave_at = NEVER``). See `build_staleness_randomness`.
-  2. **Device scan** — a ``(tau_max+1, d)`` **ring buffer** of recent models
-     is carried through the scan with a write cursor that advances on emitted
-     updates. The stale read is ``ring[(cursor − clamp(τ)) mod (tau_max+1)]``,
+  2. **Device scan** — a ``(tau_max+1, *flat_row_shape(d))`` **ring buffer**
+     of recent models (each slot in the cache's row shape) is carried
+     through the scan with a write cursor that advances on emitted updates.
+     The stale read is ``ring[(cursor − clamp(τ)) mod (tau_max+1)]``,
      exactly `history[-(τ+1)]` in the host deque. Client sampling is a traced
      categorical: ``argmax(logits + gumbels[e])`` with speed-skew
      log-probabilities; **availability is a traced-t window mask** —
@@ -98,9 +99,10 @@ from jax.flatten_util import ravel_pytree
 from repro.core import sanitize
 from repro.core.aggregators import (Aggregator, Arrival, ArrivalBatch,
                                     wants_cache_init)
-from repro.core.cache import (FlatCache, flat_row_shape, init_tree_cache,
-                              is_tree_cache_leaf, tree_cache_row,
-                              tree_cache_rows, tree_cache_set_row)
+from repro.core.cache import (FlatCache, _take_rows, flat_row_shape,
+                              init_tree_cache, is_tree_cache_leaf,
+                              tree_cache_row, tree_cache_rows,
+                              tree_cache_set_row)
 from repro.core.scan_engine import (ScanResult, _payload_chain, _to_result,
                                     default_n_events)
 from repro.core.staleness_sim import (FAULT_BYZANTINE, FAULT_EXPLODE,
@@ -291,23 +293,38 @@ def build_fault_schedule(seed: int, n_events: int, *, k_batch: int = 1,
 # ---------------------------------------------------------------------------
 
 def ring_read(ring: jnp.ndarray, cursor, tau):
-    """``history[-(tau+1)]``: the model τ emitted updates ago. `cursor` is the
-    slot holding the newest model; requires τ ≤ min(t, capacity−1). The read
-    row keeps the buffer's feature sharding (history slots are replicated,
-    features shard over ``model`` — no-op outside a mesh context)."""
+    """``history[-(tau+1)]``: the model τ emitted updates ago, as a (d,)
+    vector. The ring is ``(S, *flat_row_shape(d))``: each slot one whole
+    block in the cache's row shape, read by one dynamic slice. `cursor` is
+    the slot holding the newest model; requires τ ≤ min(t, capacity−1). The
+    read row keeps the buffer's feature sharding (history slots are
+    replicated, features shard over ``model`` — no-op outside a mesh
+    context)."""
     slot = jnp.mod(cursor - tau, ring.shape[0])
-    return shard(jax.lax.dynamic_index_in_dim(ring, slot, keepdims=False),
-                 ("cache_d",))
+    row = jax.lax.dynamic_index_in_dim(ring, slot, keepdims=False)
+    return shard(row.reshape(-1), ("cache_d",))
+
+
+def ring_read_lanes(ring: jnp.ndarray, cursor, taus):
+    """`ring_read` for each lane of the (K,) staleness vector `taus`: the K
+    models as (K, d) rows, one dynamic slice of a whole slot each (a
+    `jnp.take` over the slots lowers to a gather that reads every slot's
+    column band)."""
+    rows = _take_rows(ring, jnp.mod(cursor - taus, ring.shape[0]))
+    return shard(rows.reshape(taus.shape[0], -1), (None, "cache_d"))
 
 
 def ring_append(ring: jnp.ndarray, cursor, w, emit):
-    """``history.append(w)`` gated on `emit`: advance the cursor and write.
-    When not emitting, cursor stays and `w` (unchanged) rewrites its own slot,
-    so the write can be unconditional — trace-safe without a select on the
-    full buffer. The written buffer re-asserts its (replicated-slots,
-    model-sharded-features) layout so the scan carry never all-gathers."""
+    """``history.append(w)`` gated on `emit`: advance the cursor and write
+    the (d,) model `w` into its slot in the stored row shape, one in-place
+    dynamic update of a whole slot. When not emitting, cursor stays and `w`
+    (unchanged) rewrites its own slot, so the write can be unconditional —
+    trace-safe without a select on the full buffer. The written buffer
+    re-asserts its (replicated-slots, model-sharded-features, unsharded
+    lanes) layout so the scan carry never all-gathers."""
     cursor = jnp.where(emit, jnp.mod(cursor + 1, ring.shape[0]), cursor)
-    ring = jax.lax.dynamic_update_index_in_dim(ring, w, cursor, 0)
+    ring = jax.lax.dynamic_update_index_in_dim(
+        ring, w.reshape(ring.shape[1:]), cursor, 0)
     return shard(ring, (None, "cache_d")), cursor
 
 
@@ -526,16 +543,12 @@ def _staleness_program(*, grad_fn: Callable, params0,
         # redundantly per device; only server state shards (see
         # sharding/rules.replicate for the CPU-SPMD rationale)
         pin_payload = replicate
+        # each slot in the cache's row shape, so a slot is whole tiles
+        ring_shape = (S,) + flat_row_shape(d_tpl)
         init_ring = lambda w: shard(
-            jnp.zeros((S, d_tpl), jnp.float32).at[0].set(w),
-            (None, "cache_d"))
-        rd_ring, ap_ring = ring_read, ring_append
-
-        def rd_rings(ring, cursor, taus):
-            # batched stale reads: one gather over the (S, d) ring — `taus`
-            # is the (K,) per-lane staleness vector
-            rows = jnp.take(ring, jnp.mod(cursor - taus, S), axis=0)
-            return shard(rows, (None, "cache_d"))
+            jnp.zeros(ring_shape, jnp.float32).at[0].set(
+                w.reshape(ring_shape[1:])), (None, "cache_d"))
+        rd_ring, rd_rings, ap_ring = ring_read, ring_read_lanes, ring_append
 
         init_snaps = lambda: shard(
             jnp.zeros((marks.shape[0], d_tpl), jnp.float32),

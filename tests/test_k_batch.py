@@ -96,6 +96,11 @@ ALGOS = ["asgd", "fedbuff", "ca2fl", "ace", "aced"]
 #: still ran K = 1 and K > 1 on two separate tick bodies: running this module
 #: as a script (``PYTHONPATH=<that commit's src> python tests/test_k_batch.py``)
 #: writes every case of `GOLDEN_CASES` through `_golden_run` into this file.
+#: The flat K = 4 cases were recorded again when the K-lane stale read became
+#: whole-slot dynamic slices (`ring_read_lanes`): the rows read are the same
+#: values, but without the gather's out-of-range fill the CPU compiler rounds
+#: the fused payload arithmetic differently in the last bit. 833ab96's source
+#: with its `jnp.take` in ``mode="clip"`` gives these cases bit for bit.
 GOLDEN = Path(__file__).parent / "data" / "k_batch_golden.npz"
 #: the five rules on the flat layout at K = 1 (named by the rule alone) and
 #: K = 4, ACE on the int8 tree layout (int8 cache and history ring), and a

@@ -47,25 +47,28 @@ def test_masked_agg_full_mask_is_mean(n, d):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("d", [2, 256])
 @settings(max_examples=25, deadline=None)
 @given(st.integers(1, 10),
        st.lists(st.tuples(st.booleans(), st.integers(0, 40)),
                 min_size=1, max_size=80))
-def test_ring_buffer_reads_equal_deque_semantics(tau_max, steps):
+def test_ring_buffer_reads_equal_deque_semantics(d, tau_max, steps):
     """The scanned-staleness ring buffer (repro/core/scan_staleness.py) must
     serve history[-(tau+1)] for arbitrary emit/τ sequences — including τ
     beyond both caps (clamped to min(tau_max, len(history)-1)) and cursor
-    wraparound after more than tau_max+1 emissions."""
+    wraparound after more than tau_max+1 emissions — in both stored shapes:
+    (S, d) where 128 does not divide d, (S, d // 128, 128) where it does."""
     from collections import deque
 
     import jax
+    from repro.core.cache import flat_row_shape
     from repro.core.scan_staleness import ring_append, ring_read
 
     S = tau_max + 1
-    ring = jnp.zeros((S, 2), jnp.float32).at[0].set(0.0)
+    ring = jnp.zeros((S,) + flat_row_shape(d), jnp.float32)
     cursor = jnp.asarray(0, jnp.int32)
     history = deque(maxlen=S)
-    history.append(np.zeros(2, np.float32))
+    history.append(np.zeros(d, np.float32))
     t = 0
     for emit, tau in steps:
         tau_eff = min(tau, tau_max, len(history) - 1)
@@ -73,8 +76,8 @@ def test_ring_buffer_reads_equal_deque_semantics(tau_max, steps):
         np.testing.assert_array_equal(got, history[-(tau_eff + 1)])
         if emit:
             t += 1
-            history.append(np.full(2, float(t), np.float32))
-        w = jnp.full((2,), float(t), jnp.float32)
+            history.append(np.full(d, float(t), np.float32))
+        w = jnp.full((d,), float(t), jnp.float32)
         ring, cursor = ring_append(ring, cursor, w, jnp.asarray(emit))
 
 

@@ -13,10 +13,12 @@ import pytest
 from repro.core.aggregators import (ACED, ACEDDirect, ACEDirect,
                                     ACEIncremental, CA2FL, CA2FLDirect,
                                     FedBuff, VanillaASGD)
+from repro.core.cache import flat_row_shape
 from repro.core.scan_engine import default_n_events
 from repro.core.scan_staleness import (NEVER, build_staleness_randomness,
                                        eval_marks_for, make_staleness_runner,
                                        ring_append, ring_read,
+                                       ring_read_lanes,
                                        run_staleness_grid,
                                        run_staleness_scan,
                                        run_staleness_seeds)
@@ -361,10 +363,12 @@ def test_default_n_events_headroom_for_non_guaranteed_emitters():
 
 def _ring_vs_deque(emits, taus, tau_max, d=3):
     """Drive ring_read/ring_append and a deque(maxlen=tau_max+1) through the
-    same emit/τ sequence; every read must match history[-(τ+1)]."""
+    same emit/τ sequence; every read must match history[-(τ+1)]. The ring
+    stores each slot in the row shape `flat_row_shape(d)`, as the scan's."""
     S = tau_max + 1
     val = lambda k: np.full(d, float(k), np.float32)   # model after k updates
-    ring = jnp.zeros((S, d), jnp.float32).at[0].set(val(0))
+    ring = jnp.zeros((S,) + flat_row_shape(d), jnp.float32).at[0].set(
+        val(0).reshape(flat_row_shape(d)))
     cursor = jnp.asarray(0, jnp.int32)
     history = deque(maxlen=S)
     history.append(val(0))
@@ -383,14 +387,37 @@ def _ring_vs_deque(emits, taus, tau_max, d=3):
                 ring, cursor, jnp.asarray(val(t)), jnp.asarray(False))
 
 
+#: a row width 128 does not divide, stored (S, d), and one it does, stored
+#: (S, d // 128, 128)
+RING_WIDTHS = [3, 256]
+
+
+@pytest.mark.parametrize("d", RING_WIDTHS)
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
-def test_ring_buffer_matches_deque_random_sequences(seed):
+def test_ring_buffer_matches_deque_random_sequences(seed, d):
     rng = np.random.default_rng(seed)
     tau_max = int(rng.integers(1, 9))
     n_steps = 60
     emits = rng.random(n_steps) < 0.7
     taus = rng.integers(0, 3 * tau_max, size=n_steps)
-    _ring_vs_deque(emits.tolist(), taus.tolist(), tau_max)
+    _ring_vs_deque(emits.tolist(), taus.tolist(), tau_max, d)
+
+
+@pytest.mark.parametrize("d", RING_WIDTHS)
+def test_ring_read_lanes_equals_take(d):
+    """The K-lane stale read, one dynamic slice of a whole slot per lane,
+    returns bit for bit what `jnp.take` over the slots of the same values
+    laid out (S, d) returns, repeated and wrapping slots included."""
+    S, K = 7, 5
+    rng = np.random.default_rng(d)
+    flat = rng.normal(size=(S, d)).astype(np.float32)
+    ring = jnp.asarray(flat.reshape((S,) + flat_row_shape(d)))
+    for cursor in range(S):
+        taus = jnp.asarray(rng.integers(0, S, size=K), jnp.int32)
+        got = ring_read_lanes(ring, jnp.int32(cursor), taus)
+        want = jnp.take(jnp.asarray(flat), jnp.mod(cursor - taus, S), axis=0)
+        assert got.shape == (K, d)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

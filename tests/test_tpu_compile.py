@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.cache import FlatCache, flat_commit_batch, flat_row_shape
+from repro.core.scan_staleness import ring_append, ring_read_lanes
 from repro.kernels import cache_update, commit_batch, masked_agg, ops, quant
 from repro.kernels import row_delta
 
@@ -164,14 +165,15 @@ INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (\S+?)(?:\{[^}]*\})? "
                    r"([\w\-]+)\(")
 
 
-def _cache_copies(text, n_elems):
-    """Names of the `copy` instructions whose int8 result holds as many
-    elements as the whole cache, in whatever shape."""
+def _cache_copies(text, n_elems, dtype="s8"):
+    """Names of the `copy` instructions whose `dtype` result holds as many
+    elements as the whole cache (or ring), in whatever shape."""
     out = []
     for m in map(INSTR.match, text.splitlines()):
-        if not m or m.group(3) != "copy" or not m.group(2).startswith("s8["):
+        if not m or m.group(3) != "copy" or not m.group(2).startswith(
+                dtype + "["):
             continue
-        dims = m.group(2)[3:-1].split(",")
+        dims = m.group(2)[len(dtype) + 1:-1].split(",")
         if dims != [""] and math.prod(map(int, dims)) == n_elems:
             out.append(m.group(1))
     return out
@@ -209,6 +211,37 @@ def test_flat_commit_moves_only_the_committed_rows(one_chip, monkeypatch):
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["bytes accessed"] < 0.5 * N_CACHE * D_FLAT
+
+
+def test_flat_ring_moves_only_whole_slots(one_chip):
+    """The flat cell's f32 history ring, 51 slots of d = 2^22 stored
+    (51, *flat_row_shape(d)) and donated, compiled for one v5e chip: the
+    K = 16 stale models are read as whole slots, with no XLA gather over
+    every slot's column band (`mini-gather`), one slot is appended in
+    place, the ring is never copied whole, and the program accesses under
+    half of the ring's bytes (the K slots and the appended one are about
+    a third). Reading the slots with `jnp.take` from an (S, d) ring
+    accesses about seven times the ring's bytes."""
+    S = 51
+    shapes = (((S,) + flat_row_shape(D_FLAT), jnp.float32),
+              ((), jnp.int32), ((K,), jnp.int32),
+              ((D_FLAT,), jnp.float32), ((), jnp.bool_))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+
+    def fn(ring, cursor, taus, w, emit):
+        # the lanes' rows are read once, as the client payload reads them
+        rows = ring_read_lanes(ring, cursor, taus)
+        ring, cursor = ring_append(ring, cursor, w, emit)
+        return ring, cursor, jnp.sum(rows, axis=0)
+
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert "mini-gather" not in text
+    assert _cache_copies(text, S * D_FLAT, "f32") == []
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 0.5 * S * D_FLAT * 4
 
 
 def test_masked_agg_reads_the_stored_cache_in_place(one_chip):
